@@ -200,9 +200,11 @@ class CompiledStencil:
                  **opts) -> dict[int, jnp.ndarray]:
         """Run the stencil: live-in planes (w0, N1, ..) → facet storage.
 
-        ``opts`` pass through to the backend (e.g. ``interpret=False`` for
-        the Pallas kernels on a real TPU, ``use_kernel=True`` /
-        ``mesh=...`` for the sharded backend).
+        ``opts`` pass through to the backend (e.g. ``use_kernel=True`` /
+        ``mesh=...`` for the sharded backend).  The Pallas kernels compile
+        on a TPU and interpret on the CPU backend by themselves
+        (:func:`repro.kernels.resolve_interpret`); ``interpret=`` overrides
+        that on the kernel backends.
 
         ``trace`` overrides the compile-time ``trace=`` knob for this run:
         ``True`` records a runtime :class:`~repro.core.cfa.obs.

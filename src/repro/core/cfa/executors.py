@@ -170,9 +170,9 @@ def _wavefront(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1):
 
 
 def _pallas(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1,
-            interpret: bool = True):
-    # interpret=True is the CPU-hosted mode; on a real TPU pass
-    # interpret=False through ``CompiledStencil.__call__``.
+            interpret: bool | None = None):
+    # interpret=None compiles the kernels on a TPU and interprets them on
+    # the CPU backend (repro.kernels.resolve_interpret)
     return pipeline._sweep_wavefront(inputs, dtype, use_kernel=True,
                                      interpret=interpret)
 
@@ -183,7 +183,7 @@ def _sharded(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1, **opts):
 
 
 def _dataflow(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1,
-              use_kernel: bool = False, interpret: bool = True):
+              use_kernel: bool = False, interpret: bool | None = None):
     # the kernel path inherits the pallas backend's envelope: the
     # facet_fetch/stencil kernel family is 3-D and has no decode stage
     if use_kernel and pipeline.space.ndim != 3:
@@ -261,7 +261,7 @@ register_executor(_FnExecutor(
     ExecutorCaps(multiport=True,
                  description="port-mesh wavefront via shard_map (§VII)"),
     _sharded,
-    opts_allowed=("mesh", "axis", "assignment", "use_kernel"),
+    opts_allowed=("mesh", "axis", "assignment", "use_kernel", "interpret"),
 ))
 register_executor(_FnExecutor(
     "dataflow",

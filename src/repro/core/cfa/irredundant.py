@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from .compress import BlockCodec, get_codec
 from .facets import FacetSpec, row_major_strides
-from .transform import CFAPipeline
+from .transform import CFAPipeline, device_index
 
 __all__ = [
     "STORAGE_MODES",
@@ -235,10 +235,10 @@ def rehydrate_facets(
             sel = own == j
             offs = specs[j].offsets(x[sel]) + _virtual_shift(specs[j], facets[j])
             vals = vals.at[np.flatnonzero(sel)].set(
-                facets[j].reshape(-1)[jnp.asarray(offs)]
+                facets[j].reshape(-1)[device_index(offs)]
             )
         flat_idx = dead @ row_major_strides(arr.shape)
-        out[k] = arr.reshape(-1).at[jnp.asarray(flat_idx)].set(vals).reshape(arr.shape)
+        out[k] = arr.reshape(-1).at[device_index(flat_idx)].set(vals).reshape(arr.shape)
     return out
 
 

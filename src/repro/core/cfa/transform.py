@@ -44,6 +44,25 @@ from .spaces import IterSpace, Tiling, box_points
 __all__ = ["CFAPipeline"]
 
 
+def device_index(offsets: np.ndarray) -> jnp.ndarray:
+    """Upload host-computed flat gather offsets in the index dtype JAX uses
+    (int32 unless x64 is enabled, as it is not on the chip).
+
+    ``jnp.asarray`` silently narrows an int64 offset that does not fit,
+    and the gather then reads another element; a facet array past
+    2**31 elements would return wrong values silently.  Such an offset
+    raises here instead.
+    """
+    dtype = jax.dtypes.canonicalize_dtype(np.int64)
+    if offsets.size and int(offsets.max()) > np.iinfo(dtype).max:
+        raise OverflowError(
+            f"gather offset {int(offsets.max())} does not fit JAX's index "
+            f"dtype {np.dtype(dtype).name}; the facet array is too large to "
+            "address without 64-bit indices (jax_enable_x64)"
+        )
+    return jnp.asarray(offsets, dtype)
+
+
 @dataclasses.dataclass
 class CFAPipeline:
     #: facet storage discipline this pipeline realises; the irredundant /
@@ -235,7 +254,7 @@ class CFAPipeline:
                     offs = offs + spec.block_elems * math.prod(
                         spec.num_tiles[a] for a in spec.outer_axes[1:]
                     )
-                vals = flat[jnp.asarray(offs)]
+                vals = flat[device_index(offs)]
             if self.halo_quantize:
                 # model compressed halo traffic: each gathered message
                 # round-trips through the symmetric int8 quantizer (lossy;
@@ -278,7 +297,7 @@ class CFAPipeline:
             else:
                 idx_cols.append(pts[:, a] % spec.tile_sizes[a])
         idx = np.stack(idx_cols, axis=1)
-        return f0.reshape(-1)[jnp.asarray(idx @ row_major_strides(shape))]
+        return f0.reshape(-1)[device_index(idx @ row_major_strides(shape))]
 
     # -- execute ---------------------------------------------------------------
 
@@ -365,7 +384,7 @@ class CFAPipeline:
 
     def _sweep_wavefront(self, inputs: jnp.ndarray, dtype=jnp.float32,
                          use_kernel: bool = False,
-                         interpret: bool = True) -> dict[int, jnp.ndarray]:
+                         interpret: bool | None = None) -> dict[int, jnp.ndarray]:
         """Wavefront-parallel sweep: each wave's tiles execute as one batch
         (through the Pallas tile executor when ``use_kernel``) — the
         ``backend="wavefront"``/``"pallas"`` executors' entry point."""
@@ -404,7 +423,7 @@ class CFAPipeline:
 
     def _sweep_dataflow(self, inputs: jnp.ndarray, dtype=jnp.float32,
                         use_kernel: bool = False,
-                        interpret: bool = True) -> dict[int, jnp.ndarray]:
+                        interpret: bool | None = None) -> dict[int, jnp.ndarray]:
         """Software-pipelined wavefront sweep: fetch, compute and commit of
         consecutive tiles overlap (the host realisation of Fig. 13 DATAFLOW).
 
@@ -491,6 +510,7 @@ class CFAPipeline:
         axis: str = "port",
         assignment=None,
         use_kernel: bool = False,
+        interpret: bool | None = None,
     ) -> dict[int, jnp.ndarray]:
         """Multi-port wavefront sweep: facet arrays sharded over a mesh axis
         per the port repartition, anti-diagonal tile waves executed in
@@ -518,7 +538,7 @@ class CFAPipeline:
 
         from repro.core.cfa.multiport import assign_ports
         from repro.distributed.sharding import (
-            P, port_mesh, shard_facets, shard_map_compat)
+            P, port_mesh, shard_facets)
 
         if assignment is None:
             pa = self.port_assignment
@@ -552,7 +572,7 @@ class CFAPipeline:
         def _exec_batch(halos: jnp.ndarray) -> jnp.ndarray:
             # one shard of the wave per port-device; each tile runs the very
             # same execute_tile recurrence as the single-port sweep
-            return shard_map_compat(
+            return jax.shard_map(
                 jax.vmap(self.execute_tile), mesh=mesh,
                 in_specs=P(axis), out_specs=P(axis),
             )(halos)
@@ -591,7 +611,7 @@ class CFAPipeline:
 
                 interiors = execute_tiles_sharded(
                     self.program.name, halos, self.tiling.sizes, mesh,
-                    axis=axis, interpret=True)
+                    axis=axis, interpret=interpret)
                 outs = halos.at[(slice(None), *interior)].set(interiors)
             else:
                 outs = _exec_batch(halos)

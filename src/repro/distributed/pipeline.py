@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .sharding import shard_map_compat
-
 __all__ = ["pipeline_apply"]
 
 
@@ -41,7 +39,7 @@ def pipeline_apply(
     n_micro = x.shape[0]
 
     @functools.partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -38,7 +38,6 @@ __all__ = [
     "named",
     "DP_AXES",
     "batch_spec",
-    "shard_map_compat",
     "port_mesh",
     "shard_facets",
 ]
@@ -194,25 +193,6 @@ def translate_specs(tree, *, drop=("model",)):
     return jax.tree.map(
         lambda s: P(*[_drop(a, dropset) for a in s]),
         tree, is_leaf=lambda s: isinstance(s, P))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``shard_map`` across the jax versions this repo supports.
-
-    Recent jax exposes ``jax.shard_map`` (with ``check_vma``); the pinned
-    0.4.x series only has ``jax.experimental.shard_map.shard_map`` (with the
-    older ``check_rep`` spelling of the same knob).  All multi-port / pipeline
-    executors go through this shim so they run on either.  The default keeps
-    jax's own replication check on; callers whose bodies the checker cannot
-    analyse (Pallas calls) pass ``check_vma=False`` explicitly.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
 
 
 def port_mesh(n_ports: int, axis: str = "port") -> Mesh:

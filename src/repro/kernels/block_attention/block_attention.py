@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 __all__ = ["decode_attention"]
 
 _NEG_INF = float("-inf")
@@ -81,7 +83,7 @@ def decode_attention(
     v_blocks: jnp.ndarray,  # (B, nb, Hkv, bs, D)
     lengths: jnp.ndarray,  # (B,) int32
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:  # (B, Hq, D)
     B, nb, Hkv, bs, D = k_blocks.shape
     Hq = q.shape[1]
@@ -105,5 +107,5 @@ def decode_attention(
             pltpu.VMEM((Hq, 1), jnp.float32),  # running denominator
             pltpu.VMEM((Hq, D), jnp.float32),  # running numerator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lengths.reshape(B, 1).astype(jnp.int32), q, k_blocks, v_blocks)

@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.cfa.programs import StencilProgram, get_program
 from repro.core.cfa.transform import CFAPipeline
+from repro.kernels import resolve_interpret
 
 __all__ = ["fetch_interior_halos"]
 
@@ -109,7 +110,7 @@ def fetch_interior_halos(
     space: tuple[int, int, int],
     tile: tuple[int, int, int],
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     storage: str = "redundant",
 ) -> jnp.ndarray:
     """Halo buffers for all interior tiles, gathered block-wise.
@@ -194,5 +195,5 @@ def fetch_interior_halos(
             (None, None, None, w0 + t0, w1 + t1, w2 + t2),
             lambda i, j, k: (i, j, k, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(out_shape, facets[0].dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)

@@ -10,7 +10,7 @@ __all__ = [
 
 def fetch_interior_halos_sharded(program_name, facets, space, tile,
                                  assignment, mesh=None, *, axis="port",
-                                 interpret=True, storage="redundant"):
+                                 interpret=None, storage="redundant"):
     """Block-wise halo fetch with facet arrays resident on their ports.
 
     The multi-port analogue of ``fetch_interior_halos``: the facet arrays are

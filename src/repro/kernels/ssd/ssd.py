@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 __all__ = ["ssd_scan"]
 
 
@@ -91,7 +93,7 @@ def ssd_scan(
     C: jnp.ndarray,  # (B, T, N)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Chunked SSD scan; returns (y (B,T,H,P), final state (B,H,P,N))."""
     Bb, T, H, P = x.shape
@@ -118,6 +120,6 @@ def ssd_scan(
             jax.ShapeDtypeStruct((Bb, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((H, P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, loga, Bmat, C)
     return y, sfin

@@ -24,7 +24,7 @@ def stencil_tile_op(
     tile: tuple[int, ...],
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Execute a batch of stencil tiles; kernel path or jnp reference path."""
     if use_kernel:
@@ -39,7 +39,7 @@ def execute_tiles_sharded(
     mesh,
     *,
     axis: str = "port",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:  # (B, t0, .., t_{d-1})
     """Execute a halo batch with its shards on different port-devices.
 
@@ -52,8 +52,6 @@ def execute_tiles_sharded(
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.distributed.sharding import shard_map_compat
 
     n = int(mesh.shape[axis])
     if halos.shape[0] % n:
@@ -68,6 +66,8 @@ def execute_tiles_sharded(
     def shard(h):
         return execute_tiles(program_name, h, tile, interpret=interpret)
 
-    return shard_map_compat(
+    # check_vma=False: jax's replication checker cannot see into a
+    # pallas_call body
+    return jax.shard_map(
         shard, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False
     )(halos)

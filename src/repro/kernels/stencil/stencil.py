@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.cfa.programs import StencilProgram, get_program
+from repro.kernels import resolve_interpret
 
 
 def _tile_kernel(h_ref, o_ref, scratch, *, program: StencilProgram,
@@ -52,13 +53,14 @@ def execute_tiles(
     halos: jnp.ndarray,  # (B, w0+t0, .., w_{d-1}+t_{d-1})
     tile: tuple[int, ...],
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:  # (B, t0, .., t_{d-1})
     """Run the tile executor kernel over a batch of gathered halo buffers.
 
     Dimension-generic: ``tile`` has one entry per iteration-space axis
     (time first), so 2-D (``heat1d``), 3-D (Table I) and 4-D (``heat3d``)
-    programs share this path.
+    programs share this path.  ``interpret`` resolves through
+    :func:`repro.kernels.resolve_interpret`.
     """
     program = get_program(program_name)
     w = program.widths
@@ -78,5 +80,5 @@ def execute_tiles(
         out_specs=pl.BlockSpec((None, *tile), lambda b: (b, *zeros)),
         out_shape=jax.ShapeDtypeStruct((B, *tile), halos.dtype),
         scratch_shapes=[pltpu.VMEM(hshape, halos.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(halos)

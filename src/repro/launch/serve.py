@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import init_lm
 from repro.train.steps import make_decode_step, make_prefill_step
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--top-k", type=int, default=0, help="top-k filter (0=off)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_lm(jax.random.PRNGKey(args.seed), cfg)
     max_seq = args.prompt_len + args.gen

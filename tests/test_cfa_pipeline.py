@@ -101,3 +101,37 @@ def test_final_time_plane_recoverable():
     np.testing.assert_allclose(
         np.asarray(got[-1]), np.asarray(want[-1]), rtol=1e-12, atol=1e-12
     )
+
+
+def test_gather_offsets_refuse_to_wrap():
+    """An offset past JAX's index dtype raises instead of wrapping (with
+    x64 off, as on the chip, int64 offsets would narrow to int32)."""
+    from repro.core.cfa.transform import device_index
+
+    big = np.array([0, 2**31], np.int64)
+    with jax.enable_x64(False):
+        with pytest.raises(OverflowError, match="int32"):
+            device_index(big)
+        fits = device_index(np.array([0, 2**31 - 1], np.int64))
+        assert fits.dtype == jnp.int32 and int(fits[1]) == 2**31 - 1
+    with jax.enable_x64(True):
+        assert int(device_index(big)[1]) == 2**31
+
+
+def test_copy_in_gathers_through_the_offset_guard(monkeypatch):
+    """copy_in's facet gathers and the live-in gather both upload their
+    offsets through device_index."""
+    from repro.core.cfa import transform
+
+    seen = []
+    real = transform.device_index
+    monkeypatch.setattr(transform, "device_index",
+                        lambda offs: seen.append(len(offs)) or real(offs))
+    pipe = CFAPipeline(get_program("jacobi2d5p"), IterSpace((8, 8, 8)),
+                       Tiling((4, 4, 4)))
+    facets = pipe.load_inputs(pipe.init_facets(jnp.float32),
+                              jnp.ones((1, 8, 8), jnp.float32))
+    pipe.copy_in(facets, (0, 0, 0))  # live-in row only
+    assert len(seen) == 1
+    pipe.copy_in(facets, (1, 1, 1))  # one gather per facet piece
+    assert len(seen) == 4

@@ -114,8 +114,11 @@ _SPMD_SCRIPT = textwrap.dedent("""
     # single-device reference
     p1, o1, m1 = jax.jit(make_train_step(cfg, hp))(params, opt, batch)
 
-    # 4x2 (data x model) SPMD
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    # 4x2 (data x model) SPMD; Auto axes: the model code states shardings
+    # as constraints and lets GSPMD propagate them
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
     pspec = spec_lm(cfg)
     psh = sanitize_tree(pspec, params, mesh)
     osh = sanitize_tree(opt_state_specs(pspec, params, cfg.optimizer), opt, mesh)

@@ -18,6 +18,23 @@ from repro.kernels.ssd import ssd_decode_step, ssd_scan, ssd_scan_ref
 
 
 # ---------------------------------------------------------------------------
+# interpret-mode resolution
+# ---------------------------------------------------------------------------
+
+def test_interpret_follows_the_platform(monkeypatch):
+    from repro.kernels import resolve_interpret
+
+    assert resolve_interpret() is True  # the CPU backend interprets
+    assert resolve_interpret(False) is False  # explicit values are honoured
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False  # a TPU compiles
+    assert resolve_interpret(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        resolve_interpret()
+
+
+# ---------------------------------------------------------------------------
 # stencil tile executor
 # ---------------------------------------------------------------------------
 
