@@ -210,8 +210,9 @@ class CompiledStencil:
         ``True`` records a runtime :class:`~repro.core.cfa.obs.
         TraceRecorder` (spans + counters; read it via :meth:`last_trace`),
         ``False`` forces tracing off, ``None`` (default) follows the
-        compile.  With tracing off no recorder is allocated — the
-        executors pay one ``is None`` check per phase."""
+        compile.  With tracing off no recorder is allocated; each
+        executor phase still opens its profiler annotation
+        (:func:`~repro.core.cfa.obs.phase`)."""
         if trace is None:
             trace = self.trace_enabled
         if not trace:

@@ -37,6 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import obs
 from .facets import FacetSpec, build_facet_specs, row_major_strides
 from .programs import StencilProgram
 from .spaces import IterSpace, Tiling, box_points
@@ -85,8 +86,8 @@ class CFAPipeline:
     # pass's compression knob; False keeps results bit-exact)
     halo_quantize: bool = False
     # runtime telemetry (repro.core.cfa.obs.TraceRecorder); None = tracing
-    # off, and the executors pay exactly one `is None` check per phase —
-    # no recorder or span allocation on the hot path
+    # off: each phase then only opens its profiler annotation (obs.phase)
+    # and allocates no span
     recorder: object | None = dataclasses.field(default=None, repr=False, compare=False)
     specs: Mapping[int, FacetSpec] = dataclasses.field(init=False)
     num_tiles: tuple[int, ...] = dataclasses.field(init=False)
@@ -233,13 +234,19 @@ class CFAPipeline:
         ``sweep``/``sweep_wavefront`` hot path) keep the all-on-device path.
         """
         rec = self.recorder
-        t_start = rec.now() if rec is not None else 0.0
-        maps, lo, w = self._halo_maps(tile)
-        if rec is not None:
-            rec.add_span("halo_resolve", t_start, rec.now(),
-                         track=rec.track("fetch"), tile=list(tile),
-                         wave=int(sum(tile)), port=rec.port,
-                         **rec.record_halo(self, maps))
+        with obs.phase(rec, "copy_in", "fetch",
+                       after=lambda: rec.record_read(self, tile)):
+            with obs.phase(rec, "halo_resolve", "fetch",
+                           after=lambda: dict(tile=list(tile),
+                                              wave=int(sum(tile)),
+                                              port=rec.port,
+                                              **rec.record_halo(self, maps))):
+                maps, lo, w = self._halo_maps(tile)
+            return self._gather_halo(facets, maps, lo, w)
+
+    def _gather_halo(self, facets: dict[int, jnp.ndarray], maps, lo, w) -> jnp.ndarray:
+        """Read the resolved halo points from the facets into a fresh
+        (w + t) halo buffer."""
         t = np.array(self.tiling.sizes)
         pieces = []
         for key, pts in maps.items():
@@ -276,10 +283,6 @@ class CFAPipeline:
             H = jnp.zeros(tuple(w + t), facets[0].dtype)
             for local, vals in pieces:
                 H = H.at[tuple(jnp.asarray(local.T))].set(vals)
-        if rec is not None:
-            rec.add_span("copy_in", t_start, rec.now(),
-                         track=rec.track("fetch"),
-                         **rec.record_read(self, tile))
         return H
 
     def _gather_virtual(self, f0, spec: FacetSpec, pts: np.ndarray):
@@ -332,19 +335,16 @@ class CFAPipeline:
         self, facets: dict[int, jnp.ndarray], tile: tuple[int, ...], H: jnp.ndarray
     ) -> dict[int, jnp.ndarray]:
         rec = self.recorder
-        t_start = rec.now() if rec is not None else 0.0
-        w = self.widths
-        t = self.tiling.sizes
-        interior = H[self._interior_slices(w)]
-        out = dict(facets)
-        for k, spec in self.specs.items():
-            sl = [slice(None)] * self.space.ndim
-            sl[k] = slice(t[k] - spec.width, t[k])
-            out[k] = self._store_block(out[k], spec, tile, interior[tuple(sl)])
-        if rec is not None:
-            rec.add_span("copy_out", t_start, rec.now(),
-                         track=rec.track("commit"),
-                         **rec.record_write(self, tile))
+        with obs.phase(rec, "copy_out", "commit",
+                       after=lambda: rec.record_write(self, tile)):
+            w = self.widths
+            t = self.tiling.sizes
+            interior = H[self._interior_slices(w)]
+            out = dict(facets)
+            for k, spec in self.specs.items():
+                sl = [slice(None)] * self.space.ndim
+                sl[k] = slice(t[k] - spec.width, t[k])
+                out[k] = self._store_block(out[k], spec, tile, interior[tuple(sl)])
         return out
 
     # -- full sweep ----------------------------------------------------------------
@@ -353,20 +353,22 @@ class CFAPipeline:
         """Run the whole tiled computation through facet storage (the
         ``backend="sweep"`` executor's entry point)."""
         rec = self.recorder
-        facets = self.init_facets(dtype)
-        facets = self.load_inputs(facets, inputs.astype(dtype))
+        facets = self._loaded_facets(inputs, dtype)
         if rec is not None:
             rec.counters.add("waves", len(self.wavefronts()))
         for tile in itertools.product(*(range(n) for n in self.num_tiles)):
             H = self.copy_in(facets, tile)
-            if rec is None:
+            with obs.phase(rec, "execute_tile", "compute",
+                           tile=list(tile), wave=int(sum(tile))):
                 H = self.execute_tile(H)
-            else:
-                with rec.span("execute_tile", track=rec.track("compute"),
-                              tile=list(tile), wave=int(sum(tile))):
-                    H = self.execute_tile(H)
             facets = self.copy_out(facets, tile, H)
         return facets
+
+    def _loaded_facets(self, inputs: jnp.ndarray, dtype) -> dict[int, jnp.ndarray]:
+        """Fresh facet arrays with the live-in planes loaded: the
+        ``load_inputs`` phase every executor opens its sweep with."""
+        with obs.phase(self.recorder, "load_inputs", "commit"):
+            return self.load_inputs(self.init_facets(dtype), inputs.astype(dtype))
 
     # -- wavefront-parallel sweep ------------------------------------------------
 
@@ -389,32 +391,32 @@ class CFAPipeline:
         (through the Pallas tile executor when ``use_kernel``) — the
         ``backend="wavefront"``/``"pallas"`` executors' entry point."""
         rec = self.recorder
-        facets = self.init_facets(dtype)
-        facets = self.load_inputs(facets, inputs.astype(dtype))
+        facets = self._loaded_facets(inputs, dtype)
         interior = self._interior_slices(self.widths)
         waves = self.wavefronts()
         if rec is not None:
             rec.counters.add("waves", len(waves))
         for wave in waves:
-            halos = jnp.stack([self.copy_in(facets, t) for t in wave])
-            tok = rec.begin("execute_wave", track=rec.track("compute"),
-                            wave=int(sum(wave[0])), n_tiles=len(wave),
-                            tiles=[list(t) for t in wave],
-                            ) if rec is not None else None
-            if use_kernel:
-                from repro.kernels.stencil import execute_tiles
+            gathered = [self.copy_in(facets, t) for t in wave]
+            # the batch and the interiors' write-back exist only for the
+            # kernel or recurrence, so they are timed as part of it
+            with obs.phase(rec, "execute_wave", "compute",
+                           wave=int(sum(wave[0])), n_tiles=len(wave),
+                           tiles=[list(t) for t in wave]):
+                halos = jnp.stack(gathered)
+                # free the per-tile halos now, or they stay on the device
+                # beside the batch into the next wave's fetch
+                del gathered
+                if use_kernel:
+                    from repro.kernels.stencil import execute_tiles
 
-                interiors = execute_tiles(self.program.name, halos,
-                                          self.tiling.sizes,
-                                          interpret=interpret)
-                outs = []
-                for i in range(len(wave)):
-                    H = halos[i].at[interior].set(interiors[i])
-                    outs.append(H)
-            else:
-                outs = [self.execute_tile(halos[i]) for i in range(len(wave))]
-            if tok is not None:
-                rec.end(tok)
+                    interiors = execute_tiles(self.program.name, halos,
+                                              self.tiling.sizes,
+                                              interpret=interpret)
+                    outs = [halos[i].at[interior].set(interiors[i])
+                            for i in range(len(wave))]
+                else:
+                    outs = [self.execute_tile(halos[i]) for i in range(len(wave))]
             for tile, H in zip(wave, outs):
                 facets = self.copy_out(facets, tile, H)
         return facets
@@ -446,8 +448,7 @@ class CFAPipeline:
         whose grid pipeline double-buffers HBM<->VMEM copies against
         compute in hardware.
         """
-        facets = self.init_facets(dtype)
-        facets = self.load_inputs(facets, inputs.astype(dtype))
+        facets = self._loaded_facets(inputs, dtype)
         interior = self._interior_slices(self.widths)
         if use_kernel:
             from repro.kernels.stencil import execute_tiles
@@ -563,8 +564,7 @@ class CFAPipeline:
         mesh = mesh if mesh is not None else port_mesh(n_ports, axis)
         n_shards = int(mesh.shape[axis])
 
-        facets = self.init_facets(dtype)
-        facets = self.load_inputs(facets, inputs.astype(dtype))
+        facets = self._loaded_facets(inputs, dtype)
         facets = shard_facets(facets, assignment.facet_to_port, mesh, axis)
 
         interior = self._interior_slices(self.widths)
@@ -602,24 +602,21 @@ class CFAPipeline:
                 halos = jnp.concatenate([halos] * reps, axis=0)[:target]
             # commit the batch to the port mesh: one shard of tiles per port
             halos = jax.device_put(halos, batch_sharding)
-            tok = rec.begin("execute_wave", track=rec.track("compute"),
-                            wave=int(sum(wave[0])), n_tiles=len(wave),
-                            n_ports=n_shards,
-                            ) if rec is not None else None
-            if use_kernel:
-                from repro.kernels.stencil import execute_tiles_sharded
+            with obs.phase(rec, "execute_wave", "compute",
+                           wave=int(sum(wave[0])), n_tiles=len(wave),
+                           n_ports=n_shards):
+                if use_kernel:
+                    from repro.kernels.stencil import execute_tiles_sharded
 
-                interiors = execute_tiles_sharded(
-                    self.program.name, halos, self.tiling.sizes, mesh,
-                    axis=axis, interpret=interpret)
-                outs = halos.at[(slice(None), *interior)].set(interiors)
-            else:
-                outs = _exec_batch(halos)
-            # pull the executed planes back uncommitted so copy_out's facet
-            # updates stay resident on each facet's own port device
-            outs = np.asarray(jax.device_get(outs))
-            if tok is not None:
-                rec.end(tok)
+                    interiors = execute_tiles_sharded(
+                        self.program.name, halos, self.tiling.sizes, mesh,
+                        axis=axis, interpret=interpret)
+                    outs = halos.at[(slice(None), *interior)].set(interiors)
+                else:
+                    outs = _exec_batch(halos)
+                # pull the executed planes back uncommitted so copy_out's
+                # facet updates stay resident on each facet's own port device
+                outs = np.asarray(jax.device_get(outs))
             for i, tile in enumerate(wave):
                 if rec is not None:
                     rec.port = i * n_shards // target

@@ -81,4 +81,5 @@ def execute_tiles(
         out_shape=jax.ShapeDtypeStruct((B, *tile), halos.dtype),
         scratch_shapes=[pltpu.VMEM(hshape, halos.dtype)],
         interpret=resolve_interpret(interpret),
+        name="cfa_stencil_tile",
     )(halos)

@@ -2,8 +2,9 @@
 
 Spans from every executor, counters reconciling exactly against the plan
 accounting, Chrome trace export + schema validation, the dataflow
-backend's overlapped lanes, zero-overhead-off, and the measured-vs-
-modeled RuntimeReport with its CFA3xx fixit vocabulary.
+backend's overlapped lanes, tracing off (no recorder; each phase only a
+profiler annotation), and the measured-vs-modeled RuntimeReport with its
+CFA3xx fixit vocabulary.
 """
 import json
 import math
@@ -19,6 +20,8 @@ from repro.core.cfa.obs import (
     RuntimeReport,
     Span,
     TraceRecorder,
+    now,
+    phase,
     runtime_report,
     trace_enabled_by_env,
     validate_chrome_trace,
@@ -361,3 +364,88 @@ def test_runtime_report_facet_rows(monkeypatch):
     assert devs == sorted(devs, reverse=True)
     # the samples were routed through the shared recorder
     assert rec.find("measure_pass", cat="measure")
+
+
+# ---------------------------------------------------------------------------
+# executor phases on the profiler's clock (obs.phase)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """The names of the profiler annotations opened, in order."""
+    import jax
+
+    opened = []
+    real = jax.profiler.TraceAnnotation
+
+    def annotate(name, **kwargs):
+        opened.append(name)
+        return real(name, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotate)
+    return opened
+
+
+def test_phase_without_recorder_only_annotates(annotations):
+    with phase(None, "copy_in", "fetch", after=lambda: pytest.fail("called"),
+               tile=[0, 0, 0]):
+        pass
+    assert annotations == ["copy_in"]
+
+
+def test_phase_records_its_span_and_accounts_after_it(annotations):
+    rec = TraceRecorder()
+    rec.port = 1
+    closed = {}
+
+    def after():
+        closed["t"] = now() - rec.epoch
+        return {"n_read_bursts": 3}
+
+    with phase(rec, "copy_in", "fetch", after=after, tile=[0, 1, 0]):
+        pass
+    (span,) = rec.spans
+    assert annotations == ["copy_in"]  # the bare name, no per-tile args
+    assert (span.name, span.cat, span.track) == ("copy_in", "runtime", "port1/fetch")
+    assert dict(span.args) == {"tile": [0, 1, 0], "n_read_bursts": 3}
+    assert span.t0 + span.dur <= closed["t"]
+
+
+def test_untraced_sweep_annotates_every_phase(annotations):
+    c = cfa.compile("jacobi2d5p", SPACE, layout=TILE, backend="wavefront")
+    c(_inputs(SPACE))
+    phases = [n for n in annotations if n in (
+        "load_inputs", "copy_in", "halo_resolve", "execute_wave", "copy_out",
+        "execute_tile")]
+    assert phases[0] == "load_inputs"
+    assert {n: phases.count(n) for n in set(phases)} == {
+        "load_inputs": 1, "copy_in": N_TILES, "halo_resolve": N_TILES,
+        "execute_wave": 4, "copy_out": N_TILES}
+    # copy_in opens before its halo_resolve
+    assert all(phases[i - 1] == "copy_in"
+               for i, n in enumerate(phases) if n == "halo_resolve")
+
+
+@pytest.mark.parametrize("backend", ["sweep", "wavefront", "dataflow", "sharded"])
+def test_every_executor_loads_inputs_once(backend):
+    kw = {"n_ports": 2} if backend == "sharded" else {}
+    _, rec = _traced(backend, **kw)
+    (load,) = rec.find("load_inputs")
+    assert load.track == "port0/commit"
+    assert load.t0 <= min(s.t0 for s in rec.find("copy_in"))
+
+
+@pytest.mark.parametrize("backend", ["sweep", "wavefront", "pallas"])
+def test_untraced_facets_match_traced_bit_for_bit(backend):
+    """No profiler and no recorder: the same facets, bit for bit, as a run
+    with the recorder on."""
+    c = cfa.compile("jacobi2d5p", SPACE, layout=TILE, backend=backend)
+    x = _inputs(SPACE)
+    off = c(x, dtype=jnp.float32)
+    assert c.pipeline.recorder is None and c.last_trace() is None
+    on = c(x, dtype=jnp.float32, trace=True)
+    assert c.last_trace().counters["tiles"] == N_TILES
+    assert set(on) == set(off)
+    for k in off:
+        np.testing.assert_array_equal(np.asarray(on[k]), np.asarray(off[k]))
