@@ -16,8 +16,11 @@ form), a rectangular space and a tiling, :class:`CFAPipeline` provides
 
 On real hardware the three phases run as a coarse-grain pipeline
 (paper Fig. 13, DATAFLOW); in Pallas the same overlap comes for free from
-grid pipelining — see ``repro.kernels.stencil``.  This module is the
-correctness/reference path and is deliberately written tile-by-tile.
+grid pipelining — see ``repro.kernels.stencil``.  The executors here
+still dispatch tile by tile from the host.  The fetch is one compiled
+program a tile: ``copy_in`` reads the tile's row of the pipeline's
+:class:`FetchPlan`, gather and scatter tables resolved once and kept on
+the device.  The execute and the commit are still eager programs.
 
 The pipeline is dimension-generic (the paper's construction is, §IV-F..J):
 any d >= 2 works — one time axis plus d-1 spatial axes — so 2-D programs
@@ -27,6 +30,7 @@ any d >= 2 works — one time axis plus d-1 spatial axes — so 2-D programs
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 import typing
@@ -42,7 +46,7 @@ from .facets import FacetSpec, build_facet_specs, row_major_strides
 from .programs import StencilProgram
 from .spaces import IterSpace, Tiling, box_points
 
-__all__ = ["CFAPipeline"]
+__all__ = ["CFAPipeline", "FetchPlan"]
 
 
 def device_index(offsets: np.ndarray) -> jnp.ndarray:
@@ -62,6 +66,71 @@ def device_index(offsets: np.ndarray) -> jnp.ndarray:
             "address without 64-bit indices (jax_enable_x64)"
         )
     return jnp.asarray(offsets, dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class FetchPlan:
+    """Every tile's halo gather of one pipeline, resolved once.
+
+    ``maps[tile]`` is the tile's resolved halo (``CFAPipeline._halo_maps``)
+    and ``rows[tile]`` its row in the tables.  ``src[i]`` and ``dst[i]``
+    are ``(n_tiles, max_points)`` tables for facet array ``keys[i]``: row
+    ``r`` holds the flat facet offsets tile ``r`` reads and the flat
+    offsets in the ``shape`` halo buffer they land at.  A short row is
+    padded with source 0 and destination ``prod(shape)``, one past the
+    buffer's end, which the scatter drops.
+    """
+
+    maps: Mapping[tuple[int, ...], Mapping]
+    rows: Mapping[tuple[int, ...], object]
+    keys: tuple[int, ...]
+    src: tuple
+    dst: tuple
+    shape: tuple[int, ...]
+
+
+def _pad_rows(pieces: list[list[np.ndarray]], fill: int) -> np.ndarray:
+    """One table row per tile, its pieces end to end; short rows padded."""
+    rows = [np.concatenate(p) if p else np.zeros(0, np.int64) for p in pieces]
+    out = np.full((len(rows), max(len(r) for r in rows)), fill, np.int64)
+    for r, row in enumerate(rows):
+        out[r, :len(row)] = row
+    return out
+
+
+def _on_one_device(facets: Mapping[int, jnp.ndarray]) -> bool:
+    devices = set()
+    for arr in facets.values():
+        devices.update(arr.devices() if hasattr(arr, "devices") else ())
+    return len(devices) <= 1
+
+
+def _unravel(flat, shape: tuple[int, ...]) -> tuple:
+    """Row-major multi-index of flat offsets into an array of ``shape``;
+    the leading index is not wrapped, so an offset past the end stays
+    out of bounds."""
+    idx = []
+    for n in reversed(shape[1:]):
+        idx.append(flat % n)
+        flat = flat // n
+    return (flat, *reversed(idx))
+
+
+@functools.partial(jax.jit, static_argnames=("shape",))
+def _fetch_halo(facets, src, dst, row, *, shape):
+    """One tile's halo buffer from the fetch plan's tables: per facet
+    array, gather the tile's row of source offsets and scatter the values
+    to its row of destinations in a zero buffer; pads are dropped.
+
+    The gather indexes each facet in its own shape: a flat view of a
+    facet would make the TPU relayout the whole array first (its minor
+    dims are tiled and padded), once per tile."""
+    H = jnp.zeros(math.prod(shape), facets[0].dtype)
+    for f, s, d in zip(facets, src, dst):
+        vals = f.at[_unravel(s[row], f.shape)].get(
+            mode="promise_in_bounds", wrap_negative_indices=False)
+        H = H.at[d[row]].set(vals, mode="drop", wrap_negative_indices=False)
+    return H.reshape(shape)
 
 
 @dataclasses.dataclass
@@ -91,6 +160,9 @@ class CFAPipeline:
     recorder: object | None = dataclasses.field(default=None, repr=False, compare=False)
     specs: Mapping[int, FacetSpec] = dataclasses.field(init=False)
     num_tiles: tuple[int, ...] = dataclasses.field(init=False)
+    # the compiled fetch's tables, built on the first single-device copy_in
+    _fetch_plan: "FetchPlan | None" = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     def __post_init__(self) -> None:
         if self.space.ndim < 2:
@@ -226,14 +298,16 @@ class CFAPipeline:
     def copy_in(self, facets: dict[int, jnp.ndarray], tile: tuple[int, ...]) -> jnp.ndarray:
         """Gather the tile's flow-in into a halo buffer of shape (w + t).
 
-        When the facet arrays span several devices (port-resident facets
-        under ``sweep_wavefront_sharded``) the scatter goes through a
-        host-side buffer — mixing arrays committed to different devices in
-        one ``.at[].set`` chain is a jax error — and the combined halo comes
-        back as a fresh, uncommitted array.  Single-device facets (the
-        ``sweep``/``sweep_wavefront`` hot path) keep the all-on-device path.
+        Facets on one device take the compiled fetch: the pipeline's
+        :class:`FetchPlan`, built on the first such call, holds every
+        tile's halo maps and device-resident gather/scatter tables, and one
+        jitted program per pipeline reads the tile's row of them.  Facets
+        over several devices (port-resident facets under the ``sharded``
+        executor) and ``halo_quantize`` take the eager, piece-by-piece
+        gather of :meth:`_gather_halo`.
         """
         rec = self.recorder
+        compiled = not self.halo_quantize and _on_one_device(facets)
         with obs.phase(rec, "copy_in", "fetch",
                        after=lambda: rec.record_read(self, tile)):
             with obs.phase(rec, "halo_resolve", "fetch",
@@ -241,27 +315,58 @@ class CFAPipeline:
                                               wave=int(sum(tile)),
                                               port=rec.port,
                                               **rec.record_halo(self, maps))):
-                maps, lo, w = self._halo_maps(tile)
+                if compiled:
+                    plan = self._fetch_plan or self._build_fetch_plan()
+                    maps = plan.maps[tile]
+                else:
+                    maps, lo, w = self._halo_maps(tile)
+            if rec is not None:
+                rec.counters.add("fetch_compiled" if compiled else "fetch_eager", 1)
+            if compiled:
+                return _fetch_halo(tuple(facets[k] for k in plan.keys),
+                                   plan.src, plan.dst, plan.rows[tile],
+                                   shape=plan.shape)
             return self._gather_halo(facets, maps, lo, w)
+
+    def _source_offsets(self, key, pts: np.ndarray) -> np.ndarray:
+        """Flat offsets of resolved halo points into the facet array they
+        are read from: ``facets[key]``, or facet_0's virtual live-in row
+        for ``key == "virtual"``."""
+        if key == "virtual":
+            return self._virtual_offsets(pts)
+        spec = self.specs[key]
+        offs = spec.offsets(pts)
+        if key == 0:  # account for the virtual leading row
+            offs = offs + spec.block_elems * math.prod(
+                spec.num_tiles[a] for a in spec.outer_axes[1:]
+            )
+        return offs
+
+    def _virtual_offsets(self, pts: np.ndarray) -> np.ndarray:
+        """Flat facet_0 offsets of live-in points (x_0 < 0), which sit in
+        its virtual row."""
+        spec = self.specs[0]
+        w = spec.width
+        idx_cols = []
+        for a in spec.outer_axes:
+            idx_cols.append(
+                np.zeros(len(pts), np.int64) if a == 0 else pts[:, a] // spec.tile_sizes[a]
+            )
+        for a in spec.inner_axes:
+            if a == 0:
+                idx_cols.append(pts[:, 0] % w)  # matches the store perm for x0=-w..-1
+            else:
+                idx_cols.append(pts[:, a] % spec.tile_sizes[a])
+        return np.stack(idx_cols, axis=1) @ row_major_strides(self.facet_shape(0))
 
     def _gather_halo(self, facets: dict[int, jnp.ndarray], maps, lo, w) -> jnp.ndarray:
         """Read the resolved halo points from the facets into a fresh
-        (w + t) halo buffer."""
+        (w + t) halo buffer, one eager gather and scatter per piece."""
         t = np.array(self.tiling.sizes)
         pieces = []
         for key, pts in maps.items():
-            if key == "virtual":
-                spec = self.specs[0]
-                vals = self._gather_virtual(facets[0], spec, pts)
-            else:
-                spec = self.specs[key]
-                flat = facets[key].reshape(-1)
-                offs = spec.offsets(pts)
-                if key == 0:  # account for the virtual leading row
-                    offs = offs + spec.block_elems * math.prod(
-                        spec.num_tiles[a] for a in spec.outer_axes[1:]
-                    )
-                vals = flat[device_index(offs)]
+            flat = facets[0 if key == "virtual" else key].reshape(-1)
+            vals = flat[device_index(self._source_offsets(key, pts))]
             if self.halo_quantize:
                 # model compressed halo traffic: each gathered message
                 # round-trips through the symmetric int8 quantizer (lossy;
@@ -271,10 +376,7 @@ class CFAPipeline:
 
                 vals = dequantize_int8(*quantize_int8(vals)).astype(vals.dtype)
             pieces.append((pts - (lo - w), vals))
-        devices = set()
-        for arr in facets.values():
-            devices.update(arr.devices() if hasattr(arr, "devices") else ())
-        if len(devices) > 1:
+        if not _on_one_device(facets):
             H = np.zeros(tuple(w + t), dtype=np.dtype(facets[0].dtype))
             for local, vals in pieces:
                 H[tuple(local.T)] = np.asarray(vals)
@@ -285,22 +387,54 @@ class CFAPipeline:
                 H = H.at[tuple(jnp.asarray(local.T))].set(vals)
         return H
 
-    def _gather_virtual(self, f0, spec: FacetSpec, pts: np.ndarray):
-        """Read live-in points (x_0 < 0) from the virtual facet_0 row."""
-        w = spec.width
-        idx_cols = []
-        shape = self.facet_shape(0)
-        for a in spec.outer_axes:
-            idx_cols.append(
-                np.zeros(len(pts), np.int64) if a == 0 else pts[:, a] // spec.tile_sizes[a]
-            )
-        for a in spec.inner_axes:
-            if a == 0:
-                idx_cols.append(pts[:, 0] % w)  # matches the store perm for x0=-w..-1
-            else:
-                idx_cols.append(pts[:, a] % spec.tile_sizes[a])
-        idx = np.stack(idx_cols, axis=1)
-        return f0.reshape(-1)[device_index(idx @ row_major_strides(shape))]
+    # -- compiled fetch --------------------------------------------------------
+
+    def fetch_tables(self) -> "FetchPlan":
+        """Resolve every tile's halo once, on the host: the
+        :class:`FetchPlan` with numpy tables and each tile's row number.
+
+        The facet shapes, the storage discipline (:meth:`_halo_hosts`) and
+        the tile set are fixed for a pipeline instance, so the plan is
+        too.  Live-in points go into facet_0's table, beside its real
+        rows."""
+        tiles = list(itertools.product(*(range(n) for n in self.num_tiles)))
+        shape = tuple(wa + ta for wa, ta in zip(self.widths, self.tiling.sizes))
+        strides = row_major_strides(shape)
+        maps_of = {}
+        # per facet array, per tile: the pieces of its source and
+        # destination offsets
+        src: dict[int, list[list[np.ndarray]]] = {k: [] for k in self.specs}
+        dst: dict[int, list[list[np.ndarray]]] = {k: [] for k in self.specs}
+        for tile in tiles:
+            maps, lo, w = self._halo_maps(tile)
+            maps_of[tile] = maps
+            for k in self.specs:
+                src[k].append([])
+                dst[k].append([])
+            for key, pts in maps.items():
+                k = 0 if key == "virtual" else key
+                src[k][-1].append(self._source_offsets(key, pts))
+                dst[k][-1].append((pts - (lo - w)) @ strides)
+        keys = tuple(k for k in self.specs if any(src[k]))
+        return FetchPlan(
+            maps=maps_of, rows={tile: r for r, tile in enumerate(tiles)},
+            keys=keys, shape=shape,
+            src=tuple(_pad_rows(src[k], 0) for k in keys),
+            dst=tuple(_pad_rows(dst[k], math.prod(shape)) for k in keys),
+        )
+
+    def _build_fetch_plan(self) -> "FetchPlan":
+        """Build this pipeline's fetch plan and upload its tables and row
+        numbers, each once, through :func:`device_index`."""
+        plan = self.fetch_tables()
+        rows = list(device_index(np.arange(len(plan.rows))))
+        self._fetch_plan = dataclasses.replace(
+            plan,
+            rows={tile: rows[r] for tile, r in plan.rows.items()},
+            src=tuple(device_index(s) for s in plan.src),
+            dst=tuple(device_index(d) for d in plan.dst),
+        )
+        return self._fetch_plan
 
     # -- execute ---------------------------------------------------------------
 

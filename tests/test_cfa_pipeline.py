@@ -119,19 +119,24 @@ def test_gather_offsets_refuse_to_wrap():
 
 
 def test_copy_in_gathers_through_the_offset_guard(monkeypatch):
-    """copy_in's facet gathers and the live-in gather both upload their
-    offsets through device_index."""
+    """The fetch plan uploads its tables and row numbers through
+    device_index, once, on the first copy_in; later fetches upload
+    nothing, not even the tile's row."""
     from repro.core.cfa import transform
 
     seen = []
     real = transform.device_index
     monkeypatch.setattr(transform, "device_index",
-                        lambda offs: seen.append(len(offs)) or real(offs))
+                        lambda offs: seen.append(offs.shape) or real(offs))
     pipe = CFAPipeline(get_program("jacobi2d5p"), IterSpace((8, 8, 8)),
                        Tiling((4, 4, 4)))
     facets = pipe.load_inputs(pipe.init_facets(jnp.float32),
                               jnp.ones((1, 8, 8), jnp.float32))
-    pipe.copy_in(facets, (0, 0, 0))  # live-in row only
-    assert len(seen) == 1
-    pipe.copy_in(facets, (1, 1, 1))  # one gather per facet piece
-    assert len(seen) == 4
+    pipe.copy_in(facets, (0, 0, 0))  # live-in row only: builds the plan
+    plan = pipe._fetch_plan
+    assert plan.keys == (0, 1, 2)
+    # the row numbers, then the source and the destination tables
+    assert seen == [(8,)] + [s.shape for s in plan.src] * 2
+    with jax.transfer_guard_host_to_device("disallow"):
+        pipe.copy_in(facets, (1, 1, 1))  # one gather per facet array
+    assert len(seen) == 7

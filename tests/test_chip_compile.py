@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.core.cfa import get_program
+from repro.core.cfa import CFAPipeline, IterSpace, Tiling, get_program
+from repro.core.cfa.transform import _fetch_halo
 from repro.kernels.stencil import execute_tiles, execute_tiles_sharded
 
 CHIP_TILE = (16, 32, 128)  # chip_smoke's stencil tile (jacobi2d5p)
@@ -92,3 +93,28 @@ def test_sharded_kernel_leg_compiles_over_four_chips(topo, as_on_chip):
     # one shard of the wave per chip: each device runs 8 / 4 tiles
     w = get_program("heat3d").widths
     assert f"f32[2,{','.join(str(a + b) for a, b in zip(w, HEAT3D_TILE))}]" in text
+
+
+@pytest.mark.parametrize("program,space,tile", [
+    # the benchmark's cells (bench/configs/): PolyBench MEDIUM jacobi-2d
+    # and heat-3d at their tiles
+    ("jacobi2d5p", (200, 250, 250), (20, 50, 125)),
+    ("heat3d", (48, 40, 40, 40), (4, 20, 20, 20)),
+])
+def test_compiled_fetch_compiles_for_the_chip(program, space, tile, one_chip,
+                                              as_on_chip):
+    """The fetch program at the cells' facet, table and halo shapes."""
+    pipe = CFAPipeline(get_program(program), IterSpace(space), Tiling(tile))
+    plan = pipe.fetch_tables()
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _fetch_halo.lower(
+        tuple(arg(pipe.facet_shape(k), jnp.float32) for k in plan.keys),
+        tuple(arg(s.shape, jnp.int32) for s in plan.src),
+        tuple(arg(d.shape, jnp.int32) for d in plan.dst),
+        arg((), jnp.int32), shape=plan.shape).compile()
+    text = compiled.as_text()
+    assert "gather" in text and "scatter" in text
+    assert f"f32[{','.join(map(str, plan.shape))}]" in text
