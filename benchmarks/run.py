@@ -21,18 +21,21 @@ def _csv(name: str, us: float, derived: str) -> None:
 
 
 def table1_suite() -> None:
-    """Table I: the benchmark suite runs end-to-end through facet storage."""
+    """Table I: the benchmark suite, and the field programs beside it, run
+    end-to-end through facet storage."""
     import jax.numpy as jnp
     import numpy as np
     from repro import cfa
+    from repro.core.cfa.programs import FIELD_PROGRAMS
 
-    for name, prog in cfa.PROGRAMS.items():
+    for name, prog in {**cfa.PROGRAMS, **FIELD_PROGRAMS}.items():
         t = tuple(min(x, 4) for x in prog.default_tile)
         space = tuple(2 * x for x in t)
         compiled = cfa.compile(prog, space, layout=t, backend="sweep")
         rng = np.random.default_rng(0)
         spec = compiled.pipeline.specs[0]
-        inputs = jnp.asarray(rng.normal(size=(spec.width, *space[1:])))
+        inputs = jnp.asarray(rng.normal(
+            size=prog.with_fields((spec.width, *space[1:]), prog.n_fields)))
         t0 = time.perf_counter()
         facets = compiled(inputs)
         us = 1e6 * (time.perf_counter() - t0)

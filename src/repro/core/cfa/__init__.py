@@ -78,7 +78,8 @@ Multi-port repartition (``multiport``) — §VII future work made executable
 
 Benchmarks (``programs``)
     * ``StencilProgram`` — a Table I benchmark in post-skew normal form (§IV-E).
-    * ``PROGRAMS`` / ``get_program`` — the Table I suite registry.
+    * ``PROGRAMS`` / ``get_program`` — the Table I suite registry
+      (``get_program`` also finds ``programs.FIELD_PROGRAMS``).
 
 Pipeline (``transform``)
     * ``CFAPipeline`` — the read->execute->write tile pipeline of §V

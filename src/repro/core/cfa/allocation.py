@@ -14,8 +14,14 @@ the non-owned slots it would otherwise duplicate into, and
 ``unpack_into(..., owned=...)`` scatters only owned slots — so a
 deduplicated payload round-trips without the dead zeros clobbering values
 another facet owns.
+
+``pack_facet`` of a field spec (``FacetSpec.fields > 1``) takes the
+volume with its field axis after time, ``(N_0, F, N_1, ..)``, and packs
+each field as a scalar facet into its slot of the field axis.
 """
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 import jax.numpy as jnp
@@ -57,9 +63,16 @@ def _interleaved(spec: FacetSpec, volume_shape: tuple[int, ...]) -> list[int]:
     return shape
 
 
+def _one_field(spec: FacetSpec) -> FacetSpec:
+    return dataclasses.replace(spec, fields=1)
+
+
 def pack_facet(volume: jnp.ndarray, spec: FacetSpec) -> jnp.ndarray:
     """Extract facet array ``spec`` from a canonical value volume."""
     _check_packable(spec)
+    if spec.fields > 1:
+        return jnp.stack([pack_facet(volume[:, f], _one_field(spec))
+                          for f in range(spec.fields)], axis=len(spec.outer_axes))
     d = spec.ndim
     t_k, w, k = spec.tile_sizes[spec.axis], spec.width, spec.axis
     W = volume.reshape(_interleaved(spec, volume.shape))  # (q0, r0, q1, r1, ...)

@@ -199,6 +199,9 @@ class CompiledStencil:
                  trace: bool | None = None,
                  **opts) -> dict[int, jnp.ndarray]:
         """Run the stencil: live-in planes (w0, N1, ..) → facet storage.
+        A field program (``StencilProgram.fields``) takes and returns the
+        field axis after time: inputs (w0, F, N1, ..), and every facet
+        array holds F values per point (``repro.core.cfa.facets``).
 
         ``opts`` pass through to the backend (e.g. ``use_kernel=True`` /
         ``mesh=...`` for the sharded backend).  The Pallas kernels compile
@@ -339,7 +342,8 @@ class CompiledStencil:
         """Refill non-owned facet slots from their owners, turning an
         irredundant/compressed payload into the redundant layout's payload
         (identity under ``storage="redundant"``) — the bit-exactness bridge
-        the acceptance tests compare across disciplines."""
+        the acceptance tests compare across disciplines.  Every field of a
+        non-owned slot is refilled."""
         if self.storage == "redundant":
             return facets
         return rehydrate_facets(facets, self.pipeline.storage_map)
@@ -351,8 +355,10 @@ class CompiledStencil:
         store = "" if self.storage == "redundant" else (
             f", {self.storage} storage (footprint {r.footprint})"
         )
+        fields = (f" x {self.program.n_fields} fields "
+                  f"({', '.join(self.program.fields)})" if self.program.fields else "")
         return (
-            f"{self.program.name} @ {self.space.sizes} -> "
+            f"{self.program.name} @ {self.space.sizes}{fields} -> "
             f"layout {self.layout.key}{store}, backend {self.backend}, "
             f"target {self.target.name}{ports}: "
             f"{r.n_bursts} bursts/tile, redundancy {r.redundancy:.1%}, "

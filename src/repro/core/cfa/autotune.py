@@ -146,7 +146,8 @@ class LayoutCandidate:
 
         ``storage``/``codec`` select the facet storage discipline for CFA
         candidates (``cfa_plan``); the single-array baselines keep their own
-        (duplicate-free by construction) storage accounting.
+        (duplicate-free by construction) storage accounting.  Every scheme
+        prices the values a point holds (``program.deps.fields``).
         """
         tiling = Tiling(self.tile)
         tile = interior_tile(space, tiling)

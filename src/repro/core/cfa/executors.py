@@ -77,6 +77,8 @@ class ExecutorCaps:
     ``overlap`` — whether the backend overlaps fetch/compute/commit
     (Fig. 13 DATAFLOW); sequential backends should be modeled with
     ``BurstModel.time(..., overlap=False)``.
+    ``fields`` — whether the backend runs programs with several fields per
+    point (``StencilProgram.fields``).
     """
 
     ndims: tuple[int, ...] | None = None
@@ -84,6 +86,7 @@ class ExecutorCaps:
     kernels: bool = False
     storages: tuple[str, ...] = ("redundant", "irredundant", "compressed")
     overlap: bool = False
+    fields: bool = True
     description: str = ""
 
 
@@ -150,12 +153,13 @@ def _reference(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1):
     V = pipeline.reference_volume(inputs).astype(dtype)
     facets = pipeline.init_facets(dtype)
     facets = pipeline.load_inputs(facets, inputs)
-    w = pipeline.widths
     t = pipeline.tiling.sizes
-    interior = pipeline._interior_slices(w)
+    interior = pipeline._interior_slices(pipeline.widths)
     for tile in itertools.product(*(range(n) for n in pipeline.num_tiles)):
-        block = V[tuple(slice(q * ta, (q + 1) * ta) for q, ta in zip(tile, t))]
-        H = jnp.zeros(tuple(wa + ta for wa, ta in zip(w, t)), dtype)
+        block = V[pipeline.program.with_fields(
+            tuple(slice(q * ta, (q + 1) * ta) for q, ta in zip(tile, t)),
+            slice(None))]
+        H = jnp.zeros(pipeline.halo_shape, dtype)
         H = H.at[interior].set(block)
         facets = pipeline.copy_out(facets, tile, H)
     return facets
@@ -258,14 +262,16 @@ register_executor(_FnExecutor(
 ))
 register_executor(_FnExecutor(
     "sharded",
-    ExecutorCaps(multiport=True,
+    # its multi-device copy_in gathers eagerly through the host, one
+    # point per value; fields are not carried there
+    ExecutorCaps(multiport=True, fields=False,
                  description="port-mesh wavefront via shard_map (§VII)"),
     _sharded,
     opts_allowed=("mesh", "axis", "assignment", "use_kernel", "interpret"),
 ))
 register_executor(_FnExecutor(
     "dataflow",
-    ExecutorCaps(kernels=True, overlap=True,
+    ExecutorCaps(kernels=True, overlap=True, fields=False,
                  description="software-pipelined wavefront: fetch/compute/"
                              "commit of consecutive tiles overlap "
                              "(Fig. 13 DATAFLOW)"),
@@ -297,6 +303,11 @@ def _ineligible_reason(
         )
     if n_ports > 1 and not caps.multiport:
         return f"backend {executor.name!r} is single-port, got n_ports={n_ports}"
+    if program.fields and not caps.fields:
+        return (
+            f"backend {executor.name!r} runs scalar programs only, but "
+            f"{program.name!r} has {program.n_fields} fields {program.fields}"
+        )
     if storage not in caps.storages:
         return (
             f"backend {executor.name!r} does not implement "

@@ -44,6 +44,18 @@ direction and any of the three cumulative contiguity levels
 
 so the layout autotuner (``repro.core.cfa.autotune``) can search the whole
 family rather than hard-coding the paper's single point.
+
+A program with ``F > 1`` fields per point (``Deps.fields``) gets a field
+axis in every facet array, directly before the inner dims:
+
+    [ outer dims ] x [ F ] x [ inner dims ]
+
+so a tile's write of all fields is still one contiguous block (one burst,
+F times longer) and the spatial inner dims stay minor.  A read run that
+lies inside one tile's block becomes F runs, one per field; an extension
+read across two blocks (§IV-H) is joined only where a field's run meets
+the next field's.  ``F == 1`` has no field axis: scalar facet arrays are
+exactly the paper's.
 """
 from __future__ import annotations
 
@@ -103,6 +115,7 @@ class FacetSpec:
     outer_axes: tuple[int, ...]  # order of tile-coordinate dims
     inner_axes: tuple[int, ...]  # order of intra-tile dims; ``axis`` = modulo dim
     ext_dir: int = -1  # inter-tile contiguity direction c_k; -1 = cyclic default
+    fields: int = 1  # values per point; > 1 adds the field axis before inner
 
     def __post_init__(self) -> None:
         if self.ext_dir < 0:
@@ -126,16 +139,32 @@ class FacetSpec:
         return self.width if a == self.axis else self.tile_sizes[a]
 
     @property
-    def shape(self) -> tuple[int, ...]:
-        """Array shape: outer (tile) dims then inner (intra-tile) dims."""
+    def point_shape(self) -> tuple[int, ...]:
+        """The array's dims without the field axis: outer (tile) dims then
+        inner (intra-tile) dims."""
         return tuple(self.num_tiles[a] for a in self.outer_axes) + tuple(
             self.inner_size(a) for a in self.inner_axes
         )
 
     @property
-    def block_elems(self) -> int:
-        """Elements in one tile's facet block (one burst write)."""
+    def shape(self) -> tuple[int, ...]:
+        """Array shape: outer (tile) dims, the field axis when there are
+        several fields, then inner (intra-tile) dims."""
+        n = len(self.outer_axes)
+        pts = self.point_shape
+        fields = (self.fields,) if self.fields > 1 else ()
+        return pts[:n] + fields + pts[n:]
+
+    @property
+    def field_block_elems(self) -> int:
+        """Elements of one field in one tile's facet block."""
         return math.prod(self.inner_size(a) for a in self.inner_axes)
+
+    @property
+    def block_elems(self) -> int:
+        """Elements in one tile's facet block, every field (one burst
+        write)."""
+        return self.fields * self.field_block_elems
 
     @property
     def size(self) -> int:
@@ -171,9 +200,29 @@ class FacetSpec:
                 cols.append(r[:, a])
         return np.stack(cols, axis=1)
 
+    def point_offsets(self, pts: np.ndarray) -> np.ndarray:
+        """Row-major linear offsets of iteration points in the array
+        without its field axis (:attr:`point_shape`)."""
+        return self.coords(pts) @ row_major_strides(self.point_shape)
+
+    def spread_fields(self, offs: np.ndarray) -> np.ndarray:
+        """Offsets in the array without its field axis, moved to the array:
+        offset ``o`` of field ``f`` lands at ``(o // b) * F * b + f * b +
+        o % b``, ``b`` = :attr:`field_block_elems`.  Returns every field's
+        offsets, field after field; a scalar spec returns ``offs``.  The
+        one place the field axis's position is coded."""
+        offs = np.asarray(offs, dtype=np.int64)
+        if self.fields == 1:
+            return offs
+        b = self.field_block_elems
+        base = (offs // b) * self.block_elems + offs % b
+        return np.concatenate([base + f * b for f in range(self.fields)])
+
     def offsets(self, pts: np.ndarray) -> np.ndarray:
-        """Row-major linear offsets within the facet array for iteration points."""
-        return self.coords(pts) @ row_major_strides(self.shape)
+        """Row-major linear offsets within the facet array of the values of
+        iteration points: every field's, field after field (one per point
+        for a scalar spec)."""
+        return self.spread_fields(self.point_offsets(pts))
 
     def block_start(self, tile: Sequence[int]) -> int:
         """Linear offset of the first element of tile T's facet block."""
@@ -223,6 +272,7 @@ def build_facet_specs(
     ``ext_dirs`` maps facet axis -> inter-tile extension direction (defaults
     to the cyclic ``(k+1) mod d`` of the paper); ``contiguity`` selects one of
     ``CONTIGUITY_LEVELS``.  The defaults reproduce the paper's final layout.
+    ``deps.fields`` values per point add the field axis (module docstring).
     """
     d = space.ndim
     widths = facet_widths(deps)
@@ -259,5 +309,6 @@ def build_facet_specs(
             outer_axes=outer,
             inner_axes=inner,
             ext_dir=c,
+            fields=deps.fields,
         )
     return specs

@@ -92,8 +92,9 @@ class StorageMap:
 
     @property
     def owned_per_block(self) -> dict[int, int]:
-        """Owned slots in one tile's block, per facet."""
-        return {k: int(m.sum()) for k, m in self.owned.items()}
+        """Owned slots in one tile's block, per facet (every field's)."""
+        return {k: int(m.sum()) * self.specs[k].fields
+                for k, m in self.owned.items()}
 
     def stores(self, k: int, pts: np.ndarray) -> np.ndarray:
         """Boolean per point: does facet ``k`` *store* it — i.e. the point
@@ -114,7 +115,7 @@ class StorageMap:
     def stored_elems(self) -> int:
         """Total slots the irredundant layout stores (each value once)."""
         return sum(
-            int(self.owned[k].sum()) * (s.size // s.block_elems)
+            self.owned_per_block[k] * (s.size // s.block_elems)
             for k, s in self.specs.items()
         )
 
@@ -168,7 +169,8 @@ def build_storage_map(specs: Mapping[int, FacetSpec]) -> StorageMap:
 def dedup_facets(
     facets: dict[int, jnp.ndarray], smap: StorageMap
 ) -> dict[int, jnp.ndarray]:
-    """Zero the non-owned slots (what irredundant storage never writes)."""
+    """Zero the non-owned slots (what irredundant storage never writes);
+    a mask covers every field alike."""
     out = {}
     for k, arr in facets.items():
         mask = smap.owned[k]
@@ -206,12 +208,12 @@ def rehydrate_facets(
         if mask.all():
             continue
         arr = facets[k]
+        n_outer = len(spec.outer_axes)
         # decode every dead slot of the full array to its canonical point
         full_mask = np.broadcast_to(
-            mask, tuple(arr.shape[: len(spec.outer_axes)]) + mask.shape
+            mask, tuple(arr.shape[:n_outer]) + mask.shape
         )
         dead = np.argwhere(~full_mask)  # (n, outer+inner) multi-indices
-        n_outer = len(spec.outer_axes)
         t = np.asarray(spec.tile_sizes, dtype=np.int64)
         q = np.zeros((len(dead), spec.ndim), dtype=np.int64)
         for col, a in enumerate(spec.outer_axes):
@@ -230,14 +232,17 @@ def rehydrate_facets(
             raise AssertionError(
                 "dead slot without a lower-axis owner — storage-map bug"
             )
-        vals = jnp.zeros(len(dead), arr.dtype)
+        # every field of a dead slot, field after field
+        F = spec.fields
+        vals = jnp.zeros(F * len(dead), arr.dtype)
         for j in np.unique(own):
-            sel = own == j
-            offs = specs[j].offsets(x[sel]) + _virtual_shift(specs[j], facets[j])
-            vals = vals.at[np.flatnonzero(sel)].set(
-                facets[j].reshape(-1)[device_index(offs)]
+            sel = np.flatnonzero(own == j)
+            offs = specs[j].offsets(x[sel])
+            vals = vals.at[np.concatenate([sel + f * len(dead) for f in range(F)])].set(
+                facets[j].reshape(-1)[device_index(offs + _virtual_shift(specs[j], facets[j]))]
             )
-        flat_idx = dead @ row_major_strides(arr.shape)
+        point_shape = (arr.shape[0], *spec.point_shape[1:])
+        flat_idx = spec.spread_fields(dead @ row_major_strides(point_shape))
         out[k] = arr.reshape(-1).at[device_index(flat_idx)].set(vals).reshape(arr.shape)
     return out
 
@@ -287,6 +292,7 @@ class IrredundantPipeline(CFAPipeline):
         if mask.all():
             return super()._commit_block(arr, idx, block, spec)
         # owned slots get the new value; non-owned slots stay untouched
+        # (the mask covers the inner dims, so every field alike)
         return arr.at[idx].set(jnp.where(jnp.asarray(mask), block, arr[idx]))
 
 

@@ -378,6 +378,12 @@ def resolve_program(state: CompileState) -> CompileState:
             f'a codec only applies to storage="compressed", not '
             f'{state.storage!r}'
         )
+    if prog.fields and state.storage == "compressed":
+        raise ValueError(
+            f"{prog.name!r} has {prog.n_fields} fields {prog.fields}; "
+            'storage="compressed" stores scalar programs only (its codec '
+            "packs one value a point); use redundant or irredundant storage"
+        )
     cdc = get_codec(state.codec) if state.storage == "compressed" else None
     return dataclasses.replace(state, program=prog, space=sp, codec=cdc)
 
@@ -412,7 +418,7 @@ def estimate_facet_bytes(
     ``space`` — the distribute pass's budget metric.
 
     Facet ``k`` stores ``w_k`` planes per tile (``num_tiles x w_k x
-    prod_{a != k} t_a`` elements), so the total depends mildly on the
+    prod_{a != k} t_a`` points of ``n_fields`` values each), so the total depends mildly on the
     tiling; budget decisions are made against the program's default tile
     (clipped to the space) unless ``tile`` overrides — the layout search
     runs *after* distribution, so the exact tile is not yet known.
@@ -427,7 +433,7 @@ def estimate_facet_bytes(
             continue
         block = wk * math.prod(ta for a, ta in enumerate(t) if a != k)
         total += num_tiles * block
-    return total * elem_bytes
+    return total * program.n_fields * elem_bytes
 
 
 @compiler_pass("distribute", requires=("program", "target"),

@@ -15,6 +15,12 @@ the measurement substrate behind the Fig. 15 reproduction:
   around the flow-in (resp. flow-out), redundant transfer counted.
 * **Data tiling** (Ozturk et al. [19]): block-major array; every touched data
   tile is moved in full, redundant transfer counted.
+
+A pattern with ``deps.fields`` values per point is priced in values, not
+points: CFA runs are counted on the facet arrays with the field axis
+(``repro.core.cfa.facets``), and each baseline keeps one array per field,
+as PolyBench keeps ``fdtd-2d``'s ``ex``, ``ey`` and ``hz``, so its runs
+repeat once per field.
 """
 from __future__ import annotations
 
@@ -150,19 +156,29 @@ def count_runs(addrs: np.ndarray) -> tuple[int, ...]:
     return tuple(int(e - s + 1) for s, e in zip(starts, ends))
 
 
-def _boxed_runs(addrs: np.ndarray, gap: int) -> tuple[tuple[int, ...], int]:
+def _boxed(addrs: np.ndarray, gap: int) -> np.ndarray:
     """Rectangular over-approximation (§V-C1): cluster the needed addresses,
     close gaps smaller than ``gap`` (one burst per cluster), and return
-    (run lengths, transferred elements).  Redundancy = transferred - needed.
+    every address the clusters cover.  Redundancy = transferred - needed.
     """
     if addrs.size == 0:
-        return (), 0
+        return addrs
     a = np.unique(np.asarray(addrs, dtype=np.int64))
     breaks = np.flatnonzero(np.diff(a) > gap)
     starts = np.concatenate(([0], breaks + 1))
     ends = np.concatenate((breaks, [a.size - 1]))
-    runs = tuple(int(a[e] - a[s] + 1) for s, e in zip(starts, ends))
-    return runs, int(sum(runs))
+    return np.concatenate([np.arange(a[s], a[e] + 1) for s, e in zip(starts, ends)])
+
+
+def _field_arrays(plan: TransferPlan, fields: int) -> TransferPlan:
+    """``plan`` for ``fields`` values per point held in one array per field:
+    every run once per field."""
+    if fields == 1:
+        return plan
+    return dataclasses.replace(
+        plan, read_runs=plan.read_runs * fields, write_runs=plan.write_runs * fields,
+        read_useful=plan.read_useful * fields, write_useful=plan.write_useful * fields,
+        footprint=plan.footprint * fields if plan.footprint else plan.footprint)
 
 
 def interior_tile(space: IterSpace, tiling: Tiling) -> tuple[int, ...]:
@@ -334,7 +350,7 @@ def cfa_plan(
     storage: str = "redundant",
     codec=None,
 ) -> TransferPlan:
-    """CFA transfer plan for one tile.
+    """CFA transfer plan for one tile, in values (``deps.fields`` a point).
 
     Writes: under ``storage="redundant"`` every facet block in full — one
     burst per facet by construction; under ``"irredundant"``/``"compressed"``
@@ -350,6 +366,9 @@ def cfa_plan(
     which the autotuner treats as one candidate among the whole family.
     ``codec`` (``storage="compressed"`` only) sets ``codec_bits`` so
     ``BurstModel`` times the bursts at the fixed compression ratio.
+    Runs are counted on the facet arrays as built, with their field axis:
+    boxes close over the points, then spread over the fields
+    (:meth:`~repro.core.cfa.facets.FacetSpec.spread_fields`).
     """
     if storage not in STORAGE_MODES:
         raise ValueError(f"storage must be one of {STORAGE_MODES}: {storage!r}")
@@ -373,11 +392,11 @@ def cfa_plan(
     for k, idx in hosts.items():
         if idx.size == 0:
             continue
-        addrs = specs[k].offsets(fin[idx])
+        spec = specs[k]
+        addrs = spec.point_offsets(fin[idx])
         if boxed:
-            runs, _ = _boxed_runs(addrs, gap=specs[k].block_elems)
-        else:
-            runs = count_runs(addrs)
+            addrs = _boxed(addrs, gap=spec.field_block_elems)
+        runs = count_runs(spec.spread_fields(addrs))
         read_runs.extend(runs)
         read_hosts.extend([k] * len(runs))
 
@@ -390,9 +409,8 @@ def cfa_plan(
             fpts = fpts[owner_of(specs, fpts) == k]
             if len(fpts) == 0:
                 continue  # facet fully owned by lower axes (w_j == t_j)
-            runs = count_runs(spec.offsets(fpts))
-        else:
-            runs = count_runs(spec.offsets(fpts))
+        runs = count_runs(spec.offsets(fpts))
+        if storage == "redundant":
             assert len(runs) == 1, "full-tile contiguity violated — layout bug"
         write_runs.extend(runs)
         write_hosts.extend([k] * len(runs))
@@ -414,8 +432,8 @@ def cfa_plan(
         scheme="cfa" if boxed else "cfa-exact",
         read_runs=tuple(read_runs),
         write_runs=tuple(write_runs),
-        read_useful=int(len(fin)),
-        write_useful=int(len(fout)),
+        read_useful=int(len(fin)) * deps.fields,
+        write_useful=int(len(fout)) * deps.fields,
         read_run_hosts=tuple(read_hosts),
         write_run_hosts=tuple(write_hosts),
         storage=storage,
@@ -444,8 +462,9 @@ def original_layout_plan(
     fout = flow_out_points(space, deps, tiling, tile)
     rr = count_runs(_row_major_offsets(fin, space.sizes))
     wr = count_runs(_row_major_offsets(fout, space.sizes))
-    return TransferPlan("original", rr, wr, int(len(fin)), int(len(fout)),
-                        footprint=int(np.prod(space.sizes, dtype=np.int64)))
+    return _field_arrays(
+        TransferPlan("original", rr, wr, int(len(fin)), int(len(fout)),
+                     footprint=int(np.prod(space.sizes, dtype=np.int64))), deps.fields)
 
 
 def bounding_box_plan(
@@ -463,9 +482,10 @@ def bounding_box_plan(
 
     fin = flow_in_points(space, deps, tiling, tile)
     fout = flow_out_points(space, deps, tiling, tile)
-    return TransferPlan("bbox", _box_runs(fin), _box_runs(fout),
-                        int(len(fin)), int(len(fout)),
-                        footprint=int(np.prod(space.sizes, dtype=np.int64)))
+    return _field_arrays(
+        TransferPlan("bbox", _box_runs(fin), _box_runs(fout),
+                     int(len(fin)), int(len(fout)),
+                     footprint=int(np.prod(space.sizes, dtype=np.int64))), deps.fields)
 
 
 def data_tiling_plan(
@@ -502,11 +522,11 @@ def data_tiling_plan(
 
     fin = flow_in_points(space, deps, tiling, tile)
     fout = flow_out_points(space, deps, tiling, tile)
-    return TransferPlan(
+    return _field_arrays(TransferPlan(
         f"data-tiling{tuple(int(b) for b in blk)}",
         _block_runs(fin),
         _block_runs(fout),
         int(len(fin)),
         int(len(fout)),
         footprint=int(np.prod(layout_sizes, dtype=np.int64)),
-    )
+    ), deps.fields)

@@ -19,18 +19,27 @@ Table I benchmarks, the registry carries ``heat1d`` (a 1-D heat equation as
 a 2-D tiled space) and ``heat3d`` (a 3-D spatial heat equation as a 4-D
 space — the §IV-J regime where some k-th-level neighbours no longer merge
 into one burst).
+
+A program may carry several fields per point (``fields``, e.g. PolyBench's
+``fdtd-2d`` with ``ey``, ``ex`` and ``hz``; registered in
+``FIELD_PROGRAMS``): its planes then have a field axis right after time,
+``(F, N_1, ..)``, and ``plane_update`` maps ``(F, ..)`` history planes to
+one ``(F, ..)`` plane.  Its ``deps`` carry the count (``Deps.fields``).  A
+scalar program has no field axis at all.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .spaces import Deps, IterSpace, Tiling, facet_widths
 
-__all__ = ["StencilProgram", "PROGRAMS", "get_program"]
+__all__ = ["StencilProgram", "PROGRAMS", "FIELD_PROGRAMS", "get_program",
+           "fdtd2d_textbook"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,12 +52,38 @@ class StencilProgram:
     paper_tiles: tuple[tuple[int, ...], ...]  # Table I tile-size sweep corners
     equivalent_app: str
     skew: tuple[int, ...]  # spatial skew factors applied per spatial axis
-    # update: (prev_planes [depth][spatial+halo], widths) -> new plane [spatial]
+    # update: (prev_planes [depth][(F,) spatial+halo], widths) -> new plane
+    # [(F,) spatial]
     plane_update: Callable[[Sequence[jnp.ndarray], tuple[int, ...]], jnp.ndarray]
+    # names of the values a point holds, in update order; () = one scalar
+    fields: tuple[str, ...] = ()
+
+    def __post_init__(self) -> None:
+        if len(self.fields) == 1:
+            raise ValueError(
+                f"{self.name}: a one-field program is a scalar program; "
+                "leave fields empty")
+        if self.n_fields != max(1, len(self.fields)):
+            raise ValueError(
+                f"{self.name}: deps carry {self.deps.fields} values a point, "
+                f"fields names {len(self.fields)}")
 
     @property
     def ndim(self) -> int:
         return self.deps.ndim
+
+    @property
+    def n_fields(self) -> int:
+        """Values a point holds (1 for a scalar program): ``deps.fields``,
+        so whatever is built from the deps prices them."""
+        return self.deps.fields
+
+    def with_fields(self, dims: Sequence, entry) -> tuple:
+        """``dims`` (one entry per axis, time first) with ``entry`` as the
+        field axis after time; a scalar program has none, so its ``dims``
+        come back unchanged."""
+        dims = tuple(dims)
+        return (dims[0], entry, *dims[1:]) if self.fields else dims
 
     @property
     def widths(self) -> tuple[int, ...]:
@@ -168,6 +203,60 @@ def _gol_update(prev_planes: Sequence[jnp.ndarray], w: tuple[int, ...]) -> jnp.n
     return 2.0 * centre - neigh / 9.0
 
 
+# --- fdtd2d: 2-D FDTD (Yee) with three coupled fields; skew (1,1) -----------
+# PolyBench/C 4.2 stencils/fdtd-2d, one time step:
+#   ey[i][j] -= 0.5 * (hz[i][j] - hz[i-1][j])
+#   ex[i][j] -= 0.5 * (hz[i][j] - hz[i][j-1])
+#   hz[i][j] -= 0.7 * (ex[i][j+1] - ex[i][j] + ey[i+1][j] - ey[i][j])
+# hz reads the new ex and ey; with their updates inlined, the vector
+# (ey, ex, hz) of step t reads step t-1 on the 5-point cross, so skewed by
+# (1, 1) it has jacobi2d5p's dependence vectors.
+_FD_FIELDS = ("ey", "ex", "hz")
+_FD_C = (0.5, 0.5, 0.7)  # ey, ex, hz
+
+
+def _fdtd2d_update(prev_planes: Sequence[jnp.ndarray], w: tuple[int, ...]) -> jnp.ndarray:
+    """One (ey, ex, hz) plane from the last: PolyBench's order, the new
+    ``ex``/``ey`` that ``hz`` reads recomputed from the previous plane."""
+    p = prev_planes[-1]
+    ey, ex, hz = p[0], p[1], p[2]
+
+    def at(f, di, dj):  # textbook offset (di, dj) of step t-1, skewed
+        return _shift2(f, di - 1, dj - 1, w)
+
+    ce, cx, ch = _FD_C
+    hz_c = at(hz, 0, 0)
+    ey_n = at(ey, 0, 0) - ce * (hz_c - at(hz, -1, 0))
+    ex_n = at(ex, 0, 0) - cx * (hz_c - at(hz, 0, -1))
+    ey_s = at(ey, 1, 0) - ce * (at(hz, 1, 0) - hz_c)  # new ey[i+1][j]
+    ex_e = at(ex, 0, 1) - cx * (at(hz, 0, 1) - hz_c)  # new ex[i][j+1]
+    hz_n = hz_c - ch * (ex_e - ex_n + ey_s - ey_n)
+    return jnp.stack([ey_n, ex_n, hz_n])
+
+
+def fdtd2d_textbook(ey, ex, hz, steps: int):
+    """PolyBench ``fdtd-2d``'s three loops on the unskewed grid, ``steps``
+    time steps, in plain jnp: the update in PolyBench's order, zero
+    outside the grid instead of its range guards, no ``_fict_`` source
+    row.  Returns the ``(steps, 3, NX, NY)`` planes (ey, ex, hz) of every
+    step; the oracle the skewed ``fdtd2d`` is related to on the interior."""
+    ce, cx, ch = _FD_C
+
+    def step(f, _):
+        ey, ex, hz = f
+        up = jnp.pad(hz, ((1, 0), (0, 0)))[:-1]  # hz[i-1][j]
+        left = jnp.pad(hz, ((0, 0), (1, 0)))[:, :-1]  # hz[i][j-1]
+        ey = ey - ce * (hz - up)
+        ex = ex - cx * (hz - left)
+        ex_e = jnp.pad(ex, ((0, 0), (0, 1)))[:, 1:]  # ex[i][j+1]
+        ey_s = jnp.pad(ey, ((0, 1), (0, 0)))[1:]  # ey[i+1][j]
+        hz = hz - ch * (ex_e - ex + ey_s - ey)
+        f = jnp.stack([ey, ex, hz])
+        return f, f
+
+    return jax.lax.scan(step, jnp.stack([ey, ex, hz]), None, length=steps)[1]
+
+
 PROGRAMS: dict[str, StencilProgram] = {
     "jacobi2d5p": StencilProgram(
         name="jacobi2d5p",
@@ -238,9 +327,27 @@ PROGRAMS: dict[str, StencilProgram] = {
     ),
 }
 
+#: Programs with several coupled values per point.  None is in the paper,
+#: so they stay out of ``PROGRAMS``, the suite the figure scripts sweep
+#: (their backends and storages are scalar-only in part).
+FIELD_PROGRAMS: dict[str, StencilProgram] = {
+    "fdtd2d": StencilProgram(
+        name="fdtd2d",
+        deps=Deps(_J5.vectors, fields=len(_FD_FIELDS)),
+        default_tile=(16, 16, 16),
+        paper_tiles=(),  # not in the paper's Table I
+        equivalent_app="2-D FDTD (Yee) electromagnetics, 3 coupled fields",
+        skew=(1, 1),
+        plane_update=_fdtd2d_update,
+        fields=_FD_FIELDS,
+    ),
+}
+
 
 def get_program(name: str) -> StencilProgram:
+    """A program of ``PROGRAMS`` or ``FIELD_PROGRAMS`` by name."""
     try:
-        return PROGRAMS[name]
+        return PROGRAMS[name] if name in PROGRAMS else FIELD_PROGRAMS[name]
     except KeyError:
-        raise KeyError(f"unknown benchmark {name!r}; have {sorted(PROGRAMS)}") from None
+        raise KeyError(f"unknown benchmark {name!r}; have "
+                       f"{sorted(PROGRAMS) + sorted(FIELD_PROGRAMS)}") from None

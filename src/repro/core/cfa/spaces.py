@@ -68,13 +68,21 @@ class Deps:
     All components of every vector must be <= 0 ("backwards in all
     dimensions"), which is the paper's legality condition for rectangular
     tiling (§IV-D/E).
+
+    ``fields`` is the number of values each point holds (1 for a scalar
+    stencil): every value of ``x`` may read every value of ``x + B_q``, so
+    facet arrays and transfer plans built from the pattern hold and move
+    ``fields`` values a point (``StencilProgram.fields``).
     """
 
     vectors: tuple[tuple[int, ...], ...]
+    fields: int = 1
 
     def __post_init__(self) -> None:
         if not self.vectors:
             raise ValueError("dependence pattern must be non-empty")
+        if self.fields < 1:
+            raise ValueError(f"a point holds at least one value: fields={self.fields}")
         d = len(self.vectors[0])
         for v in self.vectors:
             if len(v) != d:

@@ -26,6 +26,14 @@ The pipeline is dimension-generic (the paper's construction is, §IV-F..J):
 any d >= 2 works — one time axis plus d-1 spatial axes — so 2-D programs
 (``heat1d``), the 3-D Table I suite, and 4-D programs (``heat3d``, the
 §IV-J regime) all run through the same code path.
+
+Field programs (``StencilProgram.fields``, e.g. ``fdtd2d``) carry a field
+axis of size F right after time in everything a tile touches: live-in
+planes ``(w0, F, N_1, ..)``, halo buffers ``(w0+t0, F, w1+t1, ..)`` and
+facet arrays (before their inner dims, see ``repro.core.cfa.facets``).
+Every table and map here is built per point and spread over the fields
+(:meth:`~repro.core.cfa.facets.FacetSpec.spread_fields`); a scalar program has no
+field axis, and its shapes and tables are the same as before fields.
 """
 from __future__ import annotations
 
@@ -186,6 +194,16 @@ class CFAPipeline:
 
     # -- storage -----------------------------------------------------------
 
+    @property
+    def fields(self) -> int:
+        """Values a point holds (``StencilProgram.n_fields``)."""
+        return self.program.n_fields
+
+    def _array_axis(self, a: int) -> int:
+        """Axis of canonical axis ``a`` in a plane stack or halo buffer,
+        which carry the field axis after time."""
+        return a + 1 if self.program.fields and a > 0 else a
+
     def facet_shape(self, k: int) -> tuple[int, ...]:
         shape = list(self.specs[k].shape)
         if k == 0:
@@ -198,12 +216,13 @@ class CFAPipeline:
     def load_inputs(
         self, facets: dict[int, jnp.ndarray], inputs: jnp.ndarray
     ) -> dict[int, jnp.ndarray]:
-        """Pack live-in planes (w_0, N_1, .., N_{d-1}) into the virtual
+        """Pack live-in planes (w_0, [F,] N_1, .., N_{d-1}) into the virtual
         facet_0 row."""
         spec = self.specs[0]
         w0 = spec.width
-        if inputs.shape != (w0, *self.space.sizes[1:]):
-            raise ValueError(f"inputs must be {(w0, *self.space.sizes[1:])}")
+        want = self.program.with_fields((w0, *self.space.sizes[1:]), self.fields)
+        if inputs.shape != want:
+            raise ValueError(f"inputs must be {want}")
         f0 = facets[0]
         t = self.tiling.sizes
         for q in itertools.product(*(range(n) for n in self.num_tiles[1:])):
@@ -211,7 +230,7 @@ class CFAPipeline:
                 slice(q[a - 1] * t[a], (q[a - 1] + 1) * t[a])
                 for a in range(1, self.space.ndim)
             )
-            blk = inputs[(slice(None), *sl)]
+            blk = inputs[self.program.with_fields((slice(None), *sl), slice(None))]
             f0 = self._store_block(f0, spec, (-1, *q), blk, virtual=True)
         facets = dict(facets)
         facets[0] = f0
@@ -229,14 +248,16 @@ class CFAPipeline:
         return tuple(idx)
 
     def _store_block(self, arr, spec: FacetSpec, tile, slab, *, virtual=False):
-        """``slab`` has canonical axis order with axis ``spec.axis`` of size w
-        indexed by slab position; store it permuted to the facet block layout
-        with the paper's (tile-dependent, in general) modulo labelling."""
+        """``slab`` has canonical axis order (the field axis after time)
+        with axis ``spec.axis`` of size w indexed by slab position; store it
+        permuted to the facet block layout with the paper's
+        (tile-dependent, in general) modulo labelling."""
         k, w, t_k = spec.axis, spec.width, spec.tile_sizes[spec.axis]
         x0 = tile[k] * t_k + t_k - w if not virtual else -w
         perm = np.argsort([(x0 + j) % w for j in range(w)])  # m -> slab j
-        slab = jnp.take(slab, jnp.asarray(perm), axis=k)
-        block = slab.transpose([a for a in spec.inner_axes])
+        slab = jnp.take(slab, jnp.asarray(perm), axis=self._array_axis(k))
+        order = [self._array_axis(a) for a in spec.inner_axes]
+        block = slab.transpose([1, *order] if self.program.fields else order)
         return self._commit_block(arr, self._block_index(spec, tile, virtual),
                                   block, spec)
 
@@ -308,7 +329,7 @@ class CFAPipeline:
         """
         rec = self.recorder
         compiled = not self.halo_quantize and _on_one_device(facets)
-        with obs.phase(rec, "copy_in", "fetch",
+        with obs.phase(rec, "copy_in", "fetch", fields=self.fields,
                        after=lambda: rec.record_read(self, tile)):
             with obs.phase(rec, "halo_resolve", "fetch",
                            after=lambda: dict(tile=list(tile),
@@ -331,7 +352,7 @@ class CFAPipeline:
     def _source_offsets(self, key, pts: np.ndarray) -> np.ndarray:
         """Flat offsets of resolved halo points into the facet array they
         are read from: ``facets[key]``, or facet_0's virtual live-in row
-        for ``key == "virtual"``."""
+        for ``key == "virtual"``; every field's, field after field."""
         if key == "virtual":
             return self._virtual_offsets(pts)
         spec = self.specs[key]
@@ -344,7 +365,7 @@ class CFAPipeline:
 
     def _virtual_offsets(self, pts: np.ndarray) -> np.ndarray:
         """Flat facet_0 offsets of live-in points (x_0 < 0), which sit in
-        its virtual row."""
+        its virtual row; every field's, field after field."""
         spec = self.specs[0]
         w = spec.width
         idx_cols = []
@@ -357,12 +378,29 @@ class CFAPipeline:
                 idx_cols.append(pts[:, 0] % w)  # matches the store perm for x0=-w..-1
             else:
                 idx_cols.append(pts[:, a] % spec.tile_sizes[a])
-        return np.stack(idx_cols, axis=1) @ row_major_strides(self.facet_shape(0))
+        shape = (spec.point_shape[0] + 1, *spec.point_shape[1:])
+        return spec.spread_fields(np.stack(idx_cols, axis=1) @ row_major_strides(shape))
+
+    @property
+    def halo_shape(self) -> tuple[int, ...]:
+        """A tile's halo buffer: (w + t) per axis, the field axis after
+        time."""
+        return self.program.with_fields(
+            tuple(wa + ta for wa, ta in zip(self.widths, self.tiling.sizes)),
+            self.fields)
+
+    def _halo_index(self, pts: np.ndarray, lo, w) -> np.ndarray:
+        """Multi-indices into the halo buffer of every field of halo points,
+        field after field, as :meth:`_source_offsets` orders them."""
+        local = pts - (lo - w)
+        if not self.program.fields:
+            return local
+        return np.concatenate([
+            np.insert(local, 1, f, axis=1) for f in range(self.fields)])
 
     def _gather_halo(self, facets: dict[int, jnp.ndarray], maps, lo, w) -> jnp.ndarray:
         """Read the resolved halo points from the facets into a fresh
         (w + t) halo buffer, one eager gather and scatter per piece."""
-        t = np.array(self.tiling.sizes)
         pieces = []
         for key, pts in maps.items():
             flat = facets[0 if key == "virtual" else key].reshape(-1)
@@ -375,14 +413,14 @@ class CFAPipeline:
                     dequantize_int8, quantize_int8)
 
                 vals = dequantize_int8(*quantize_int8(vals)).astype(vals.dtype)
-            pieces.append((pts - (lo - w), vals))
+            pieces.append((self._halo_index(pts, lo, w), vals))
         if not _on_one_device(facets):
-            H = np.zeros(tuple(w + t), dtype=np.dtype(facets[0].dtype))
+            H = np.zeros(self.halo_shape, dtype=np.dtype(facets[0].dtype))
             for local, vals in pieces:
                 H[tuple(local.T)] = np.asarray(vals)
             H = jnp.asarray(H)
         else:
-            H = jnp.zeros(tuple(w + t), facets[0].dtype)
+            H = jnp.zeros(self.halo_shape, facets[0].dtype)
             for local, vals in pieces:
                 H = H.at[tuple(jnp.asarray(local.T))].set(vals)
         return H
@@ -398,7 +436,7 @@ class CFAPipeline:
         too.  Live-in points go into facet_0's table, beside its real
         rows."""
         tiles = list(itertools.product(*(range(n) for n in self.num_tiles)))
-        shape = tuple(wa + ta for wa, ta in zip(self.widths, self.tiling.sizes))
+        shape = self.halo_shape
         strides = row_major_strides(shape)
         maps_of = {}
         # per facet array, per tile: the pieces of its source and
@@ -414,7 +452,7 @@ class CFAPipeline:
             for key, pts in maps.items():
                 k = 0 if key == "virtual" else key
                 src[k][-1].append(self._source_offsets(key, pts))
-                dst[k][-1].append((pts - (lo - w)) @ strides)
+                dst[k][-1].append(self._halo_index(pts, lo, w) @ strides)
         keys = tuple(k for k in self.specs if any(src[k]))
         return FetchPlan(
             maps=maps_of, rows={tile: r for r, tile in enumerate(tiles)},
@@ -447,8 +485,10 @@ class CFAPipeline:
         )
 
     def _interior_slices(self, w: tuple[int, ...]) -> tuple[slice, ...]:
-        """Index of the tile interior within a (w + t)-shaped halo buffer."""
-        return tuple(slice(w[a], None) for a in range(self.space.ndim))
+        """Index of the tile interior within a (w + t)-shaped halo buffer
+        (every field)."""
+        return self.program.with_fields(
+            tuple(slice(w[a], None) for a in range(self.space.ndim)), slice(None))
 
     def execute_tile(self, H: jnp.ndarray) -> jnp.ndarray:
         """Run the plane recurrence over the halo buffer; returns the filled
@@ -469,15 +509,15 @@ class CFAPipeline:
         self, facets: dict[int, jnp.ndarray], tile: tuple[int, ...], H: jnp.ndarray
     ) -> dict[int, jnp.ndarray]:
         rec = self.recorder
-        with obs.phase(rec, "copy_out", "commit",
+        with obs.phase(rec, "copy_out", "commit", fields=self.fields,
                        after=lambda: rec.record_write(self, tile)):
             w = self.widths
             t = self.tiling.sizes
             interior = H[self._interior_slices(w)]
             out = dict(facets)
             for k, spec in self.specs.items():
-                sl = [slice(None)] * self.space.ndim
-                sl[k] = slice(t[k] - spec.width, t[k])
+                sl = [slice(None)] * interior.ndim
+                sl[self._array_axis(k)] = slice(t[k] - spec.width, t[k])
                 out[k] = self._store_block(out[k], spec, tile, interior[tuple(sl)])
         return out
 
@@ -493,7 +533,7 @@ class CFAPipeline:
         for tile in itertools.product(*(range(n) for n in self.num_tiles)):
             H = self.copy_in(facets, tile)
             with obs.phase(rec, "execute_tile", "compute",
-                           tile=list(tile), wave=int(sum(tile))):
+                           tile=list(tile), wave=int(sum(tile)), fields=self.fields):
                 H = self.execute_tile(H)
             facets = self.copy_out(facets, tile, H)
         return facets
@@ -501,7 +541,10 @@ class CFAPipeline:
     def _loaded_facets(self, inputs: jnp.ndarray, dtype) -> dict[int, jnp.ndarray]:
         """Fresh facet arrays with the live-in planes loaded: the
         ``load_inputs`` phase every executor opens its sweep with."""
-        with obs.phase(self.recorder, "load_inputs", "commit"):
+        rec = self.recorder
+        if rec is not None:
+            rec.counters.add("facet_fields", self.fields)
+        with obs.phase(rec, "load_inputs", "commit"):
             return self.load_inputs(self.init_facets(dtype), inputs.astype(dtype))
 
     # -- wavefront-parallel sweep ------------------------------------------------
@@ -536,7 +579,7 @@ class CFAPipeline:
             # kernel or recurrence, so they are timed as part of it
             with obs.phase(rec, "execute_wave", "compute",
                            wave=int(sum(wave[0])), n_tiles=len(wave),
-                           tiles=[list(t) for t in wave]):
+                           tiles=[list(t) for t in wave], fields=self.fields):
                 halos = jnp.stack(gathered)
                 # free the per-tile halos now, or they stay on the device
                 # beside the batch into the next wave's fetch
@@ -766,7 +809,8 @@ class CFAPipeline:
         w = self.widths
         N = self.space.sizes
         depth = w[0]
-        pad = [(w[a], 0) for a in range(1, self.space.ndim)]
+        pad = self.program.with_fields(
+            [(w[a], 0) for a in range(self.space.ndim)], (0, 0))[1:]
         hist = [jnp.asarray(inputs[m]) for m in range(depth)]  # planes -w0..-1
         planes = []
         for _ in range(N[0]):

@@ -124,6 +124,11 @@ def fetch_interior_halos(
     prog = get_program(program_name)
     from repro.core.cfa import IterSpace, Tiling, build_facet_specs
 
+    if prog.fields:
+        raise ValueError(
+            f"the facet_fetch kernel's BlockSpecs address scalar facets; "
+            f"{prog.name!r} has {prog.n_fields} fields (CFAPipeline.copy_in "
+            "fetches them)")
     if len(space) != 3 or prog.ndim != 3:
         raise ValueError(
             "the facet_fetch kernel's static BlockSpecs address 3-D facet "

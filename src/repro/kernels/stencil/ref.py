@@ -16,14 +16,15 @@ from repro.core.cfa.programs import StencilProgram, get_program
 
 def execute_tiles_ref(
     program: StencilProgram | str,
-    halos: jnp.ndarray,  # (B, w0+t0, .., w_{d-1}+t_{d-1})
+    halos: jnp.ndarray,  # (B, w0+t0, [F,] .., w_{d-1}+t_{d-1})
     tile: tuple[int, ...],
-) -> jnp.ndarray:  # (B, t0, .., t_{d-1})
+) -> jnp.ndarray:  # (B, t0, [F,] .., t_{d-1})
     if isinstance(program, str):
         program = get_program(program)
     w = program.widths
     d = len(tile)
-    spatial = tuple(slice(w[a], None) for a in range(1, d))
+    spatial = program.with_fields(
+        tuple(slice(w[a], None) for a in range(d)), slice(None))[1:]
 
     def one(H):
         for s in range(tile[0]):

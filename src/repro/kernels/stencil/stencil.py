@@ -16,7 +16,9 @@ processes one iteration tile:
 Block shapes: the minor two dims of both the halo buffer and the output are
 the spatial dims, which the caller sizes to multiples of (8, 128) for
 sublane/lane alignment — the CFA layout guarantees those extents are
-contiguous in HBM, which is what makes these DMAs "bursts".
+contiguous in HBM, which is what makes these DMAs "bursts".  A field
+program's blocks carry the field axis after time, ``(w0+t0, F, ..)``, so
+the spatial dims stay minor.
 """
 from __future__ import annotations
 
@@ -36,7 +38,8 @@ def _tile_kernel(h_ref, o_ref, scratch, *, program: StencilProgram,
                  tile: tuple[int, ...]):
     w = program.widths
     d = len(tile)
-    spatial = tuple(slice(w[a], None) for a in range(1, d))
+    spatial = program.with_fields(
+        tuple(slice(w[a], None) for a in range(d)), slice(None))[1:]
     # Stage the halo buffer into the scratch working set once; all further
     # reads/writes are VMEM-local.
     scratch[...] = h_ref[...]
@@ -50,11 +53,11 @@ def _tile_kernel(h_ref, o_ref, scratch, *, program: StencilProgram,
 @functools.partial(jax.jit, static_argnames=("program_name", "tile", "interpret"))
 def execute_tiles(
     program_name: str,
-    halos: jnp.ndarray,  # (B, w0+t0, .., w_{d-1}+t_{d-1})
+    halos: jnp.ndarray,  # (B, w0+t0, [F,] .., w_{d-1}+t_{d-1})
     tile: tuple[int, ...],
     *,
     interpret: bool | None = None,
-) -> jnp.ndarray:  # (B, t0, .., t_{d-1})
+) -> jnp.ndarray:  # (B, t0, [F,] .., t_{d-1})
     """Run the tile executor kernel over a batch of gathered halo buffers.
 
     Dimension-generic: ``tile`` has one entry per iteration-space axis
@@ -67,18 +70,20 @@ def execute_tiles(
     d = len(tile)
     if program.ndim != d:
         raise ValueError(f"{program_name} is {program.ndim}-D, tile is {d}-D")
-    hshape = tuple(w[a] + tile[a] for a in range(d))
+    hshape = program.with_fields(tuple(w[a] + tile[a] for a in range(d)),
+                                 program.n_fields)
+    oshape = program.with_fields(tile, program.n_fields)
     if halos.shape[1:] != hshape:
         raise ValueError(f"halos must be (B, {hshape}), got {halos.shape}")
     B = halos.shape[0]
-    zeros = (0,) * d
+    zeros = (0,) * len(hshape)
     kernel = functools.partial(_tile_kernel, program=program, tile=tile)
     return pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[pl.BlockSpec((None, *hshape), lambda b: (b, *zeros))],
-        out_specs=pl.BlockSpec((None, *tile), lambda b: (b, *zeros)),
-        out_shape=jax.ShapeDtypeStruct((B, *tile), halos.dtype),
+        out_specs=pl.BlockSpec((None, *oshape), lambda b: (b, *zeros)),
+        out_shape=jax.ShapeDtypeStruct((B, *oshape), halos.dtype),
         scratch_shapes=[pltpu.VMEM(hshape, halos.dtype)],
         interpret=resolve_interpret(interpret),
         name="cfa_stencil_tile",

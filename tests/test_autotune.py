@@ -22,6 +22,7 @@ from repro.core.cfa import (
     pack_facet,
 )
 from repro.core.cfa.plans import original_layout_plan, interior_tile
+from repro.core.cfa.programs import FIELD_PROGRAMS, get_program
 from repro.core.cfa.spaces import Tiling
 
 
@@ -193,9 +194,9 @@ def test_decision_records_score_and_roundtrips(tmp_path):
 # quality: never worse than the hand-coded plans (the acceptance criterion)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", sorted(PROGRAMS))
+@pytest.mark.parametrize("name", sorted(PROGRAMS) + sorted(FIELD_PROGRAMS))
 def test_decision_beats_every_hand_coded_plan(name, tmp_path):
-    prog = PROGRAMS[name]
+    prog = get_program(name)
     space = _small_space(prog)
     decision = autotune(prog, space, AXI_ZC706, budget=48, seed=0,
                         cache_dir=tmp_path)
@@ -209,11 +210,11 @@ def test_decision_beats_every_hand_coded_plan(name, tmp_path):
     assert decision.best_cfa().effective_bw >= base["cfa"].effective_bw - 1e-9
 
 
-@pytest.mark.parametrize("name", sorted(PROGRAMS))
+@pytest.mark.parametrize("name", sorted(PROGRAMS) + sorted(FIELD_PROGRAMS))
 def test_chosen_plan_not_worse_than_original_layout(name, tmp_path):
     """The winner's modeled bursts and transfer time never exceed the
     original-layout baseline (which moves the minimum possible bytes)."""
-    prog = PROGRAMS[name]
+    prog = get_program(name)
     space = _small_space(prog)
     decision = autotune(prog, space, AXI_ZC706, budget=48, seed=0,
                         cache_dir=tmp_path)

@@ -24,6 +24,7 @@ from repro.kernels.stencil import execute_tiles, execute_tiles_sharded
 CHIP_TILE = (16, 32, 128)  # chip_smoke's stencil tile (jacobi2d5p)
 CHIP_WAVE = 64  # its largest wave, in tiles
 HEAT3D_TILE = (4, 8, 8, 128)  # chip_smoke's sharded-phase tile
+FDTD2D_TILE = (20, 50, 120)  # the fdtd2d-medium cell's tile (bench/configs/)
 
 
 @pytest.fixture(scope="module")
@@ -63,14 +64,18 @@ def as_on_chip():
 
 
 def _halo_batch(program, tile, batch, sharding):
-    w = get_program(program).widths
-    shape = (batch, *(wa + ta for wa, ta in zip(w, tile)))
+    prog = get_program(program)
+    shape = (batch, *prog.with_fields(
+        tuple(wa + ta for wa, ta in zip(prog.widths, tile)), prog.n_fields))
     return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
 
 @pytest.mark.parametrize("program,tile,batch", [
     ("jacobi2d5p", CHIP_TILE, CHIP_WAVE),
     ("heat3d", HEAT3D_TILE, 4),
+    # the fdtd2d-medium cell's tile: a (21, 3, 52, 122) halo block of three
+    # fields, 8 tiles in its largest wave
+    ("fdtd2d", FDTD2D_TILE, 8),
 ])
 def test_execute_tiles_compiles_to_tpu_kernel(program, tile, batch, one_chip,
                                               as_on_chip):
@@ -100,6 +105,7 @@ def test_sharded_kernel_leg_compiles_over_four_chips(topo, as_on_chip):
     # and heat-3d at their tiles
     ("jacobi2d5p", (200, 250, 250), (20, 50, 125)),
     ("heat3d", (48, 40, 40, 40), (4, 20, 20, 20)),
+    ("fdtd2d", (100, 200, 240), FDTD2D_TILE),
 ])
 def test_compiled_fetch_compiles_for_the_chip(program, space, tile, one_chip,
                                               as_on_chip):

@@ -32,6 +32,8 @@ SPACES = {
     "jacobi2d5p": ((8, 12, 8), (4, 4, 4)),
     "heat3d": ((8, 8, 8, 8), (4, 4, 4, 4)),
     "heat1d": ((8, 12), (4, 4)),
+    # three fields a point: every table entry spread over the field axis
+    "fdtd2d": ((6, 12, 16), (2, 4, 8)),
 }
 STORAGES = {"redundant": CFAPipeline, "irredundant": IrredundantPipeline}
 CASES = [pytest.param(name, storage, id=f"{name}-{storage}")
@@ -47,7 +49,8 @@ def _pipe(name, storage, **kw):
 def _inputs(pipe):
     w0 = pipe.specs[0].width
     rng = np.random.default_rng(0)
-    return jnp.asarray(rng.normal(size=(w0, *pipe.space.sizes[1:])))
+    return jnp.asarray(rng.normal(size=pipe.program.with_fields(
+        (w0, *pipe.space.sizes[1:]), pipe.fields)))
 
 
 def _tiles(pipe):
@@ -176,14 +179,15 @@ def test_table_offset_past_int32_raises_on_first_fetch(name, storage,
     """An offset past int32 in the tables of the last row of time tiles
     (which the first tile, reading only live-in planes, never touches)
     raises OverflowError on the first copy_in, and no plan is kept."""
-    real = FacetSpec.offsets
+    real = FacetSpec.point_offsets
     pipe = _pipe(name, storage)
     last_row = pipe.space.sizes[0] - pipe.tiling.sizes[0]
 
-    def offsets(spec, pts):
+    def point_offsets(spec, pts):
         return real(spec, pts) + np.where(pts[:, 0] >= last_row, 2**31, 0)
 
-    monkeypatch.setattr(FacetSpec, "offsets", offsets)
+    # every offset a table holds, each field's too, derives from these
+    monkeypatch.setattr(FacetSpec, "point_offsets", point_offsets)
     with jax.enable_x64(False):
         facets = pipe.init_facets(jnp.float32)
         with pytest.raises(OverflowError, match="int32"):

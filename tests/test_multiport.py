@@ -24,6 +24,7 @@ from repro.core.cfa import (
     repartition,
 )
 from repro.core.cfa.autotune import LayoutDecision
+from repro.core.cfa.programs import FIELD_PROGRAMS
 
 
 def _default_setup(name):
@@ -37,7 +38,7 @@ def _default_setup(name):
 # scheduling quality: LPT vs round-robin, balance
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", sorted(PROGRAMS))
+@pytest.mark.parametrize("name", sorted(PROGRAMS) + sorted(FIELD_PROGRAMS))
 @pytest.mark.parametrize("n_ports", [2, 3])
 def test_facet_lpt_never_worse_than_round_robin(name, n_ports):
     prog, space, tiling = _default_setup(name)

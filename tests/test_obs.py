@@ -124,6 +124,52 @@ def test_reconcile_exact(backend, storage):
     assert r["observed"]["tiles"] == N_TILES
 
 
+@pytest.mark.parametrize("backend,storage", [
+    ("wavefront", "redundant"),
+    ("pallas", "redundant"),
+    ("sweep", "irredundant"),
+])
+def test_reconcile_exact_for_field_programs(backend, storage):
+    """fdtd2d's three fields a point: the counters reconcile exactly, and
+    read and write ``F`` times the values of jacobi2d5p, the scalar
+    program with the same dependences, at the same tile."""
+    prog = get_program("fdtd2d")
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(1, prog.n_fields, *SPACE[1:])))
+    c = cfa.compile("fdtd2d", SPACE, layout=TILE, backend=backend,
+                    storage=storage, trace=True)
+    c(x, dtype=jnp.float64)
+    rec = c.last_trace()
+    r = rec.reconcile(c.pipeline)
+    assert r["ok"], r["mismatches"]
+    _, scalar = _traced("sweep", storage=storage)
+    for k in ("read_elems", "write_elems"):
+        assert rec.counters[k] == prog.n_fields * scalar.counters[k], k
+    assert rec.counters["facet_fields"] == prog.n_fields
+    assert scalar.counters["facet_fields"] == 1
+
+
+def test_facet_burst_bytes_reads_the_recorded_counters():
+    """The benchmark's ``facet_burst_bytes`` reader: 4 bytes a value over
+    the planned bursts of the recorded sweep, and nothing without a
+    recorder or bursts."""
+    import importlib.util
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "metrics" / "facet_burst_bytes.py"
+    spec = importlib.util.spec_from_file_location("facet_burst_bytes", path)
+    metric = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(metric)
+
+    _, rec = _traced("wavefront")
+    c = rec.counters
+    want = 4 * (c["read_elems"] + c["write_elems"]) / (c["bursts_read"] + c["bursts_write"])
+    assert metric.read(SimpleNamespace(layer={"recorder": rec})) == want > 0
+    assert metric.read(SimpleNamespace(layer={"recorder": None})) is None
+    assert metric.read(SimpleNamespace(layer={"recorder": TraceRecorder()})) is None
+
+
 def test_reconcile_catches_skipped_tile():
     c, rec = _traced("sweep")
     # forge a recorder that "missed" one tile's commit
