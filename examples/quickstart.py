@@ -65,13 +65,14 @@ print(f"\ncompiled stencil == untiled oracle: max err {err:.2e}")
 assert err < 1e-5
 
 # 4. rebind backends: same layout, different executors ----------------------
-# (sweep and wavefront are bit-identical to each other; the Pallas kernel
-# backend above is jitted, so it agrees to float rounding, not bitwise)
+# (sweep runs the plane recurrence eagerly; the Pallas kernel backend above
+# and wavefront run it in compiled programs, so they agree to float
+# rounding, not bitwise)
 sweep = compiled.lower("sweep")(inputs)
 wave = compiled.lower("wavefront")(inputs)
-assert all(bool(jnp.array_equal(sweep[k], wave[k])) for k in facets)
 for k in facets:
-    np.testing.assert_allclose(np.asarray(facets[k]), np.asarray(sweep[k]),
-                               rtol=1e-5, atol=1e-5)
-print("backends sweep == wavefront (bit-exact), pallas == both (to rounding)")
+    for other in (facets, wave):
+        np.testing.assert_allclose(np.asarray(other[k]), np.asarray(sweep[k]),
+                                   rtol=1e-5, atol=1e-5)
+print("backends pallas == sweep == wavefront (to rounding)")
 print("OK")

@@ -8,13 +8,15 @@ registered objects with *declared* capabilities, so backend selection, N-D
 gating and port-count validation happen in exactly one place
 (:func:`check_backend` / :func:`select_backend`).
 
-Registered backends (all return the same payload — the facet-storage dict,
-bit-exact across backends):
+Registered backends (all return the same payload — the facet-storage dict;
+bit-exact across the eager backends, to float rounding where the recurrence
+runs in a compiled program, ``wavefront`` and ``pallas``):
 
 * ``reference`` — untiled oracle (``reference_volume``) scattered into facet
   storage; the ground truth everything else is compared against.
 * ``sweep``     — the tile-by-tile reference loop of §V (Fig. 13).
-* ``wavefront`` — anti-diagonal waves of independent tiles, batched (jnp).
+* ``wavefront`` — anti-diagonal waves of independent tiles, each wave's plane
+  recurrences one compiled program.
 * ``pallas``    — wavefront sweep through the Pallas tile-executor kernel
   (``repro.kernels.stencil``), paired with the ``facet_fetch`` read engine's
   layout family; declared 3-D only — the paper's kernel configuration.
@@ -243,7 +245,8 @@ register_executor(_FnExecutor(
 ))
 register_executor(_FnExecutor(
     "wavefront",
-    ExecutorCaps(description="batched anti-diagonal tile waves (jnp)"),
+    ExecutorCaps(description="batched anti-diagonal tile waves, one "
+                             "compiled plane recurrence a wave"),
     _wavefront,
 ))
 register_executor(_FnExecutor(

@@ -20,8 +20,9 @@ attributable timeline:
   profiler session sees the phases on the device trace's clock, plus the
   recorder span of the same name when a recorder is attached.
 * :class:`Counters` — a deterministic metrics registry (bursts issued,
-  wire vs stored bytes, tiles, waves, halo indirections, the fetch path
-  each tile took: ``fetch_compiled`` / ``fetch_eager``) whose totals
+  wire vs stored bytes, tiles, waves, halo indirections, the fetch and
+  execute paths each tile took: ``fetch_compiled`` / ``fetch_eager``,
+  ``execute_compiled`` / ``execute_eager``) whose totals
   :meth:`TraceRecorder.reconcile` checks *exactly* against
   ``BurstModel.plan_bytes`` and the per-tile plans' read/write
   accounting — the runtime counterpart of the CFA1xx static verifier.

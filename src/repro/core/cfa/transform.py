@@ -20,7 +20,10 @@ grid pipelining — see ``repro.kernels.stencil``.  The executors here
 still dispatch tile by tile from the host.  The fetch is one compiled
 program a tile: ``copy_in`` reads the tile's row of the pipeline's
 :class:`FetchPlan`, gather and scatter tables resolved once and kept on
-the device.  The execute and the commit are still eager programs.
+the device.  The wavefront executor runs a wave's plane recurrences as
+one compiled program (``execute_wave``); the ``sweep`` oracle and the
+dataflow host path keep the eager ``execute_tile``.  The commit is still
+a chain of eager programs.
 
 The pipeline is dimension-generic (the paper's construction is, §IV-F..J):
 any d >= 2 works — one time axis plus d-1 spatial axes — so 2-D programs
@@ -170,6 +173,9 @@ class CFAPipeline:
     num_tiles: tuple[int, ...] = dataclasses.field(init=False)
     # the compiled fetch's tables, built on the first single-device copy_in
     _fetch_plan: "FetchPlan | None" = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
+    # the compiled execute, built on the first execute_wave
+    _wave_program: typing.Callable | None = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
 
     def __post_init__(self) -> None:
@@ -503,6 +509,24 @@ class CFAPipeline:
             H = H.at[(w[0] + s, *spatial)].set(plane)
         return H
 
+    def execute_wave(self, halos) -> tuple[jnp.ndarray, ...]:
+        """Run :meth:`execute_tile` over a wave's halo buffers as one
+        device program: stack them, ``jax.vmap`` the recurrence, and
+        return each tile's filled buffer.
+
+        One jitted program a pipeline, built on the first call; it
+        compiles once per distinct wave size, and the halos are donated
+        to it (each filled buffer may take its halo's memory).  The
+        arithmetic is :meth:`execute_tile`'s, but XLA may fuse a multiply
+        and an add into one rounding where the eager recurrence rounds
+        twice."""
+        if self._wave_program is None:
+            def run(hs):
+                return tuple(jax.vmap(self.execute_tile)(jnp.stack(hs)))
+
+            self._wave_program = jax.jit(run, donate_argnums=0)
+        return self._wave_program(tuple(halos))
+
     # -- copy-out ---------------------------------------------------------------
 
     def copy_out(
@@ -535,6 +559,8 @@ class CFAPipeline:
             with obs.phase(rec, "execute_tile", "compute",
                            tile=list(tile), wave=int(sum(tile)), fields=self.fields):
                 H = self.execute_tile(H)
+            if rec is not None:
+                rec.counters.add("execute_eager", 1)
             facets = self.copy_out(facets, tile, H)
         return facets
 
@@ -564,8 +590,9 @@ class CFAPipeline:
     def _sweep_wavefront(self, inputs: jnp.ndarray, dtype=jnp.float32,
                          use_kernel: bool = False,
                          interpret: bool | None = None) -> dict[int, jnp.ndarray]:
-        """Wavefront-parallel sweep: each wave's tiles execute as one batch
-        (through the Pallas tile executor when ``use_kernel``) — the
+        """Wavefront-parallel sweep: each wave's tiles execute as one batch,
+        through the Pallas tile executor when ``use_kernel``, else as one
+        compiled plane recurrence (:meth:`execute_wave`) — the
         ``backend="wavefront"``/``"pallas"`` executors' entry point."""
         rec = self.recorder
         facets = self._loaded_facets(inputs, dtype)
@@ -580,20 +607,23 @@ class CFAPipeline:
             with obs.phase(rec, "execute_wave", "compute",
                            wave=int(sum(wave[0])), n_tiles=len(wave),
                            tiles=[list(t) for t in wave], fields=self.fields):
-                halos = jnp.stack(gathered)
-                # free the per-tile halos now, or they stay on the device
-                # beside the batch into the next wave's fetch
-                del gathered
                 if use_kernel:
                     from repro.kernels.stencil import execute_tiles
 
+                    halos = jnp.stack(gathered)
+                    # free the per-tile halos now, or they stay on the
+                    # device beside the batch into the next wave's fetch
+                    del gathered
                     interiors = execute_tiles(self.program.name, halos,
                                               self.tiling.sizes,
                                               interpret=interpret)
                     outs = [halos[i].at[interior].set(interiors[i])
                             for i in range(len(wave))]
                 else:
-                    outs = [self.execute_tile(halos[i]) for i in range(len(wave))]
+                    # the halos are donated: the program takes their memory
+                    outs = self.execute_wave(gathered)
+                    if rec is not None:
+                        rec.counters.add("execute_compiled", len(wave))
             for tile, H in zip(wave, outs):
                 facets = self.copy_out(facets, tile, H)
         return facets
@@ -606,7 +636,7 @@ class CFAPipeline:
         """Software-pipelined wavefront sweep: fetch, compute and commit of
         consecutive tiles overlap (the host realisation of Fig. 13 DATAFLOW).
 
-        Same plane arithmetic and same facet-commit order as
+        Same plane update and same facet-commit order as
         ``_sweep_wavefront`` — only the *interleaving* changes: while tile
         ``j``'s execute is in flight (jax dispatches it asynchronously),
         tile ``j+1``'s halo is gathered and tile ``j-1``'s result is
@@ -625,6 +655,7 @@ class CFAPipeline:
         whose grid pipeline double-buffers HBM<->VMEM copies against
         compute in hardware.
         """
+        rec = self.recorder
         facets = self._loaded_facets(inputs, dtype)
         interior = self._interior_slices(self.widths)
         if use_kernel:
@@ -644,9 +675,10 @@ class CFAPipeline:
                     # not an error
                     warnings.filterwarnings("ignore", message=r".*[Dd]onat")
                     H = stage(H)
+                if rec is not None:
+                    rec.counters.add("execute_eager", 1)
                 return self.execute_tile(H)
 
-        rec = self.recorder
         waves = self.wavefronts()
         if rec is not None:
             rec.counters.add("waves", len(waves))

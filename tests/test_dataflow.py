@@ -97,14 +97,19 @@ def test_dataflow_kernel_path_matches_sweep(name, space, tile, storage):
 
 
 def test_dataflow_matches_wavefront_and_reference():
-    """Three-way agreement: dataflow == wavefront == reference oracle."""
+    """Three-way agreement: dataflow == reference oracle bit for bit, and
+    wavefront, whose wave runs as one compiled program (a multiply and an
+    add may fuse into one rounding), with both at the fused-program
+    tolerance of the kernel path."""
     name, space, tile = CASES[0]
     df = _run(name, space, tile, "dataflow", "redundant")
     wf = _run(name, space, tile, "wavefront", "redundant")
     ref = _run(name, space, tile, "reference", "redundant")
     for k in ref:
-        assert (np.asarray(df[k]) == np.asarray(wf[k])).all(), f"facet {k}"
         assert (np.asarray(df[k]) == np.asarray(ref[k])).all(), f"facet {k}"
+        for other in (df, ref):
+            assert np.allclose(np.asarray(wf[k]), np.asarray(other[k]),
+                               rtol=1e-5, atol=1e-5), f"facet {k}"
 
 
 # --------------------------------------------------------------------------

@@ -96,12 +96,13 @@ def test_textbook_loops_follow_polybench_order():
     assert np.allclose(out, np.stack([ey1, ex1, hz1]), rtol=0, atol=1e-12)
 
 
-# float32 tolerances: sweep and wavefront run the very plane update of the
-# reference eagerly, tile by tile, so they agree to the bit; the Pallas
-# kernel (interpreted here) evaluates the same expression in its own
-# fused program, which may round differently by a few float32 ulps of
-# values of order 1 per plane over the 6 planes: 1e-5 absolute.
-BACKENDS = {"reference": 0.0, "sweep": 0.0, "wavefront": 0.0, "pallas": 1e-5}
+# float32 tolerances: sweep runs the very plane update of the reference
+# eagerly, tile by tile, so they agree to the bit; the Pallas kernel
+# (interpreted here) and wavefront, which runs a wave's recurrences as one
+# compiled program, evaluate the same expression in a fused program, where
+# a multiply and an add may become one rounding: a few float32 ulps of
+# values of order 1 per plane over the 6 planes, 1e-5 absolute.
+BACKENDS = {"reference": 0.0, "sweep": 0.0, "wavefront": 1e-5, "pallas": 1e-5}
 
 
 @pytest.mark.parametrize("backend", sorted(BACKENDS))

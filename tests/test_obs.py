@@ -78,6 +78,22 @@ def test_wavefront_executes_per_wave():
     assert not rec.find("execute_tile")
 
 
+@pytest.mark.parametrize("backend,counter", [
+    ("wavefront", "execute_compiled"),
+    ("sweep", "execute_eager"),
+    ("dataflow", "execute_eager"),
+])
+def test_execute_path_is_counted(backend, counter):
+    """One ``execute_compiled`` a tile where a wave's recurrences run as
+    one compiled program, one ``execute_eager`` a tile where the eager
+    recurrence runs; the recorder still reconciles."""
+    c, rec = _traced(backend)
+    other = ({"execute_compiled", "execute_eager"} - {counter}).pop()
+    assert rec.counters.get(counter) == N_TILES
+    assert rec.counters.get(other) == 0
+    assert rec.reconcile(c.pipeline)["ok"]
+
+
 def test_sharded_attributes_ports():
     pipe = cfa.compile("jacobi2d5p", SPACE, layout=TILE,
                        backend="sharded", n_ports=2, trace=True)
