@@ -384,7 +384,6 @@ def compile(
     overlap: bool = False,
     autotune_kwargs: Mapping | None = None,
     host_budget: int | None = None,
-    halo_quantize: bool = False,
     passes: PassPipeline | None = None,
     verify: bool = False,
     trace: bool | None = None,
@@ -427,9 +426,6 @@ def compile(
       is split over enough ports that each shard fits (``n_ports`` is
       raised, backend auto-selection lowers to ``sharded``) instead of
       raising.  ``None`` (default) never splits.
-    * ``halo_quantize`` — route every halo gather through the int8
-      compression hooks of ``repro.distributed.compression`` (lossy halo
-      traffic; off by default so results stay bit-exact).
     * ``passes`` — a custom :class:`~repro.core.cfa.passes.PassPipeline`
       to lower with instead of :func:`~repro.core.cfa.passes.
       default_pipeline` (stage order is validated at pipeline assembly).
@@ -454,7 +450,7 @@ def compile(
         layout=layout, backend=backend, storage=storage, codec=codec,
         overlap=overlap,
         autotune_kwargs=dict(autotune_kwargs) if autotune_kwargs else None,
-        host_budget=host_budget, halo_quantize=halo_quantize,
+        host_budget=host_budget,
     )
     pipe = default_pipeline() if passes is None else passes
     if verify:

@@ -170,15 +170,6 @@ class LayoutCandidate:
             return data_tiling_plan(space, program.deps, tiling, tile, block=self.block)
         raise ValueError(f"unknown layout scheme {self.scheme!r}")
 
-    def is_default_cfa_layout(self, ndim: int) -> bool:
-        """True iff this is the paper's final layout family (the only one the
-        ``facet_fetch`` Pallas kernel's BlockSpecs hard-code)."""
-        if self.scheme != "cfa" or (self.contiguity or "intra-tile") != "intra-tile":
-            return False
-        if self.ext_dirs is None:
-            return True
-        return all(c == extension_dir(k, ndim) for k, c in self.ext_dirs)
-
 
 @dataclasses.dataclass(frozen=True)
 class ScoredLayout:
@@ -364,37 +355,14 @@ class LayoutDecision:
             port_bytes=tuple(loads),
         )
 
-    def best_cfa(self, *, kernel_compatible: bool = False) -> ScoredLayout:
+    def best_cfa(self) -> ScoredLayout:
         """Best CFA-family candidate (facet storage is what the pipeline and
-        the Pallas kernels consume).
-
-        ``kernel_compatible`` further restricts to layouts the
-        ``facet_fetch`` kernel's static BlockSpecs can address: 3-D spaces
-        only (the kernel's block maps are 3-D), the paper's default layout,
-        facet widths dividing the tile, and at least two tiles per axis (so
-        an interior exists).
-        """
-        d = len(self.space)
-        if kernel_compatible and d != 3:
-            raise LookupError(
-                f"the facet_fetch kernel addresses 3-D layouts only; "
-                f"{self.program} @ {self.space} is {d}-D"
-            )
+        the Pallas kernels consume)."""
         for s in self.ranked:
-            c = s.candidate
-            if c.scheme != "cfa":
-                continue
-            if kernel_compatible:
-                if not c.is_default_cfa_layout(d):
-                    continue
-                if any(w and t % w for w, t in zip(self.widths, c.tile)):
-                    continue
-                if any(n // t < 2 for n, t in zip(self.space, c.tile)):
-                    continue
-            return s
+            if s.candidate.scheme == "cfa":
+                return s
         raise LookupError(
-            f"no {'kernel-compatible ' if kernel_compatible else ''}CFA candidate "
-            f"in decision for {self.program} @ {self.space}"
+            f"no CFA candidate in decision for {self.program} @ {self.space}"
         )
 
     # -- serialisation ------------------------------------------------------

@@ -18,8 +18,8 @@ runs in a compiled program, ``wavefront`` and ``pallas``):
 * ``wavefront`` — anti-diagonal waves of independent tiles, each wave's plane
   recurrences one compiled program.
 * ``pallas``    — wavefront sweep through the Pallas tile-executor kernel
-  (``repro.kernels.stencil``), paired with the ``facet_fetch`` read engine's
-  layout family; declared 3-D only — the paper's kernel configuration.
+  (``repro.kernels.stencil``); declared 3-D only, the rank ``auto`` picks it
+  for — the paper's kernel configuration.
 * ``sharded``   — port-mesh wavefront: facet arrays resident on their
   assigned port's device, waves executed via ``shard_map`` (§VII).
 * ``dataflow``  — software-pipelined wavefront: fetch, compute and commit of
@@ -73,9 +73,8 @@ class ExecutorCaps:
     ``kernels`` — whether the backend drives the Pallas kernels (so callers
     know an ``interpret=`` knob applies).
     ``storages`` — the facet storage disciplines the backend implements
-    (``repro.core.cfa.irredundant.STORAGE_MODES``); a kernel backend whose
-    read engine has no decompression stage must not silently accept
-    ``storage="compressed"``.
+    (``repro.core.cfa.irredundant.STORAGE_MODES``); a backend that does not
+    run a discipline must not silently accept it.
     ``overlap`` — whether the backend overlaps fetch/compute/commit
     (Fig. 13 DATAFLOW); sequential backends should be modeled with
     ``BurstModel.time(..., overlap=False)``.
@@ -190,8 +189,8 @@ def _sharded(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1, **opts):
 
 def _dataflow(pipeline: CFAPipeline, inputs, *, dtype, n_ports=1,
               use_kernel: bool = False, interpret: bool | None = None):
-    # the kernel path inherits the pallas backend's envelope: the
-    # facet_fetch/stencil kernel family is 3-D and has no decode stage
+    # the kernel path inherits the pallas backend's envelope (3-D spaces,
+    # no compressed storage)
     if use_kernel and pipeline.space.ndim != 3:
         raise BackendError(
             "backend 'dataflow' drives the Pallas tile executor only for "
@@ -252,14 +251,12 @@ register_executor(_FnExecutor(
 register_executor(_FnExecutor(
     "pallas",
     ExecutorCaps(ndims=(3,), kernels=True,
-                 # the facet_fetch read engine addresses raw facet blocks
-                 # (redundant, or irredundant via the owner-block
-                 # indirection); it has no in-kernel decode stage, so the
-                 # compressed discipline is declared unsupported
+                 # backend="auto" picks pallas on 3-D spaces only, and no
+                 # test or cell runs the tile kernel on other ranks or over
+                 # compressed storage, so the envelope declares neither
                  storages=("redundant", "irredundant"),
                  description="wavefront sweep through the Pallas tile "
-                             "executor (facet_fetch/stencil kernel family, "
-                             "3-D only)"),
+                             "executor (stencil kernel, 3-D only)"),
     _pallas,
     opts_allowed=("interpret",),
 ))

@@ -23,8 +23,8 @@ storage discipline as a first-class subsystem:
   one.
 * :class:`IrredundantPipeline` — a ``CFAPipeline`` whose ``copy_out``
   commits only owned slots and whose ``copy_in`` resolves every halo read
-  to the owner facet's storage (the owner-facet indirection; the Pallas
-  read engine mirrors it in ``repro.kernels.facet_fetch``).
+  to the owner facet's storage (the owner-facet indirection; the compiled
+  fetch's ``FetchPlan`` bakes the same owner reads into its tables).
 * :class:`CompressedPipeline` — additionally passes every committed block
   through a fixed-ratio :class:`~repro.core.cfa.compress.BlockCodec`
   round-trip, so results reflect exactly what compressed storage preserved

@@ -39,9 +39,7 @@ space is split over enough ports that every shard fits, ``n_ports`` is
 raised accordingly, and backend auto-selection then lowers to the sharded
 executor (facet arrays resident on their port's device via
 ``repro.distributed.sharding.port_mesh``) — an oversized space compiles to
-sharded execution instead of raising.  ``halo_quantize=True`` additionally
-routes every halo gather through the int8 compression hooks of
-``repro.distributed.compression`` (lossy, off by default).
+sharded execution instead of raising.
 """
 from __future__ import annotations
 
@@ -84,7 +82,7 @@ class PipelineError(ValueError):
 class CompileState:
     """The immutable lowering artifact: request fields in, artifacts accreted.
 
-    The request fields (``program`` .. ``halo_quantize``) mirror
+    The request fields (``program`` .. ``host_budget``) mirror
     ``cfa.compile``'s signature and are *refined in place* — after
     ``resolve_program``/``validate_target`` they hold concrete
     ``StencilProgram``/``IterSpace``/``Target`` objects.  The artifact
@@ -105,9 +103,8 @@ class CompileState:
     overlap: bool = False
     autotune_kwargs: Mapping | None = None
     # the distribute pass: per-host facet-memory budget in bytes (None =
-    # single-host, never split) and the optional int8 halo-traffic hook
+    # single-host, never split)
     host_budget: int | None = None
-    halo_quantize: bool = False
 
     # -- artifacts (accreted per stage) --------------------------------------
     candidate: LayoutCandidate | None = None
@@ -614,7 +611,6 @@ def lower_backend(state: CompileState) -> CompileState:
         contiguity=cand.contiguity or "intra-tile",
         decision=state.decision,
         port_assignment=state.port_assignment,
-        halo_quantize=state.halo_quantize,
     )
     if state.storage == "redundant":
         pipeline = CFAPipeline(state.program, state.space,

@@ -161,10 +161,6 @@ class CFAPipeline:
     # the compile-time facet->port split (the port_repartition pass); the
     # sharded sweep prefers it over re-deriving one from the decision
     port_assignment: object | None = dataclasses.field(default=None, repr=False, compare=False)
-    # round-trip every halo gather through the int8 compression hooks of
-    # repro.distributed.compression (lossy halo traffic, the distribute
-    # pass's compression knob; False keeps results bit-exact)
-    halo_quantize: bool = False
     # runtime telemetry (repro.core.cfa.obs.TraceRecorder); None = tracing
     # off: each phase then only opens its profiler annotation (obs.phase)
     # and allocates no span
@@ -330,11 +326,11 @@ class CFAPipeline:
         tile's halo maps and device-resident gather/scatter tables, and one
         jitted program per pipeline reads the tile's row of them.  Facets
         over several devices (port-resident facets under the ``sharded``
-        executor) and ``halo_quantize`` take the eager, piece-by-piece
-        gather of :meth:`_gather_halo`.
+        executor) take the eager, piece-by-piece gather of
+        :meth:`_gather_halo`.  Where the facets sit is the only choice.
         """
         rec = self.recorder
-        compiled = not self.halo_quantize and _on_one_device(facets)
+        compiled = _on_one_device(facets)
         with obs.phase(rec, "copy_in", "fetch", fields=self.fields,
                        after=lambda: rec.record_read(self, tile)):
             with obs.phase(rec, "halo_resolve", "fetch",
@@ -411,14 +407,6 @@ class CFAPipeline:
         for key, pts in maps.items():
             flat = facets[0 if key == "virtual" else key].reshape(-1)
             vals = flat[device_index(self._source_offsets(key, pts))]
-            if self.halo_quantize:
-                # model compressed halo traffic: each gathered message
-                # round-trips through the symmetric int8 quantizer (lossy;
-                # see repro.distributed.compression)
-                from repro.distributed.compression import (
-                    dequantize_int8, quantize_int8)
-
-                vals = dequantize_int8(*quantize_int8(vals)).astype(vals.dtype)
             pieces.append((self._halo_index(pts, lo, w), vals))
         if not _on_one_device(facets):
             H = np.zeros(self.halo_shape, dtype=np.dtype(facets[0].dtype))
@@ -758,9 +746,8 @@ class CFAPipeline:
             decision = self.decision
             if decision is not None and getattr(decision, "n_ports", 1) == n_ports:
                 # only reuse the decision's facet->port split when this
-                # pipeline actually instantiates the candidate it was
-                # computed for (a kernel-compatible re-pick may have chosen
-                # a different, kernel-addressable layout)
+                # pipeline instantiates the candidate it was computed for
+                # (a pipeline built by hand may tile differently)
                 try:
                     best = decision.best_cfa()
                 except LookupError:

@@ -323,10 +323,8 @@ def test_legacy_shims_are_gone():
             f"CFAPipeline.{name} was deleted; use cfa.compile() "
             f"(or the _-prefixed internal from the executors)"
         )
-    import repro.kernels.facet_fetch as facet_fetch
     import repro.kernels.stencil as stencil
     assert not hasattr(stencil, "execute_tiles_from_autotuned")
-    assert not hasattr(facet_fetch, "fetch_interior_halos_from_autotuned")
     # the deprecation machinery itself left with its last clients
     with pytest.raises(ImportError):
         import repro.core.cfa.deprecation  # noqa: F401

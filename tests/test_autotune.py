@@ -12,7 +12,6 @@ import jax.numpy as jnp
 
 from repro.core.cfa import (
     AXI_ZC706,
-    CFAPipeline,
     IterSpace,
     LayoutDecision,
     PROGRAMS,
@@ -340,22 +339,3 @@ def test_autotuned_compile_matches_oracle(tmp_path):
     err = float(jnp.abs(facets[0][1:] - pack_facet(V.astype(jnp.float32),
                                                    spec)).max())
     assert err < 1e-4
-
-
-def test_autotuned_kernel_compatible_fetch(tmp_path):
-    from repro.kernels.facet_fetch import fetch_interior_halos
-
-    prog = PROGRAMS["jacobi2d5p"]
-    space = (16, 16, 16)
-    decision = autotune(prog, space, AXI_ZC706, budget=24, seed=0,
-                        cache_dir=tmp_path)
-    cand = decision.best_cfa(kernel_compatible=True).candidate
-    assert cand.is_default_cfa_layout(3)
-    pipe = CFAPipeline(prog, IterSpace(space), Tiling(cand.tile))
-    rng = np.random.default_rng(1)
-    inputs = jnp.asarray(rng.normal(size=(pipe.specs[0].width, *space[1:])),
-                         jnp.float32)
-    facets = pipe._sweep(inputs)
-    halos = fetch_interior_halos(prog.name, facets, space, cand.tile)
-    ref = pipe.copy_in(facets, tuple(1 for _ in range(3)))
-    assert float(jnp.abs(halos[0, 0, 0] - ref).max()) < 1e-6

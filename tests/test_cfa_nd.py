@@ -214,13 +214,6 @@ def test_nd_autotune_valid_decision(name, space, tmp_path):
         assert err < 1e-12
 
 
-def test_nd_kernel_compatible_requires_3d(tmp_path):
-    dec = autotune("heat1d", (16, 16), AXI_ZC706, budget=8, seed=0,
-                   cache_dir=tmp_path)
-    with pytest.raises(LookupError, match="3-D"):
-        dec.best_cfa(kernel_compatible=True)
-
-
 def test_4d_repartition_conserves_traffic():
     prog = get_program("heat3d")
     sp, tl = IterSpace((12, 12, 12, 12)), Tiling((4, 4, 4, 4))

@@ -2,9 +2,9 @@
 reads every tile's halo through device-resident tables.
 
 It must equal the eager piece-by-piece gather bit for bit, compile once
-per pipeline, stay off the two paths that keep the eager gather (facets
-over several devices, quantized halos), and keep the int32 offset guard
-for every tile's table.
+per pipeline, stay off the one path that keeps the eager gather (facets
+over several devices), and keep the int32 offset guard for every tile's
+table.
 """
 import itertools
 import json
@@ -34,16 +34,21 @@ SPACES = {
     "heat1d": ((8, 12), (4, 4)),
     # three fields a point: every table entry spread over the field axis
     "fdtd2d": ((6, 12, 16), (2, 4, 8)),
+    # the 9-point stencil's corner pieces
+    "jacobi2d9p": ((8, 8, 8), (4, 4, 4)),
+    # facets of width 2
+    "gaussian": ((4, 16, 16), (2, 8, 8)),
+    # a history of depth 3 along time
+    "smith-waterman-3seq": ((9, 8, 8), (3, 4, 4)),
 }
 STORAGES = {"redundant": CFAPipeline, "irredundant": IrredundantPipeline}
 CASES = [pytest.param(name, storage, id=f"{name}-{storage}")
          for name in SPACES for storage in STORAGES]
 
 
-def _pipe(name, storage, **kw):
+def _pipe(name, storage):
     space, tile = SPACES[name]
-    return STORAGES[storage](get_program(name), IterSpace(space), Tiling(tile),
-                             **kw)
+    return STORAGES[storage](get_program(name), IterSpace(space), Tiling(tile))
 
 
 def _inputs(pipe):
@@ -88,21 +93,17 @@ def test_wavefront_sweep_compiles_the_fetch_once(name, storage):
         assert np.array_equal(np.asarray(first[k]), np.asarray(again[k]))
 
 
-@pytest.mark.parametrize("path", ["single-device", "quantized"])
 @pytest.mark.parametrize("name,storage", CASES)
-def test_fetch_path_is_counted(name, storage, path):
-    """One ``fetch_compiled`` or ``fetch_eager`` a tile, by the path the
-    fetch took; ``halo_quantize`` keeps the eager path, and the recorder
-    still reconciles."""
-    quantized = path == "quantized"
-    pipe = _pipe(name, storage, halo_quantize=quantized)
+def test_fetch_path_is_counted(name, storage):
+    """One ``fetch_compiled`` a tile from facets on one device, none
+    eager, and the recorder still reconciles."""
+    pipe = _pipe(name, storage)
     rec = obs.TraceRecorder()
     pipe.recorder = rec
     pipe._sweep_wavefront(_inputs(pipe), dtype=jnp.float64)
-    n = len(_tiles(pipe))
-    assert rec.counters.get("fetch_eager") == (n if quantized else 0)
-    assert rec.counters.get("fetch_compiled") == (0 if quantized else n)
-    assert (pipe._fetch_plan is None) == quantized
+    assert rec.counters.get("fetch_eager") == 0
+    assert rec.counters.get("fetch_compiled") == len(_tiles(pipe))
+    assert pipe._fetch_plan is not None
     assert rec.reconcile(pipe)["ok"]
 
 

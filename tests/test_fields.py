@@ -333,12 +333,3 @@ def test_pack_carries_the_field_axis():
     for k in c.pipeline.specs:
         got = facets[k][1:] if k == 0 else facets[k]
         np.testing.assert_array_equal(np.asarray(packed[k]), np.asarray(got))
-
-
-def test_facet_fetch_kernel_refuses_fields():
-    from repro.kernels.facet_fetch.facet_fetch import fetch_interior_halos
-
-    pipe = CFAPipeline(get_program("fdtd2d"), IterSpace((6, 12, 24)), Tiling((2, 4, 8)))
-    with pytest.raises(ValueError, match="fields"):
-        fetch_interior_halos("fdtd2d", pipe.init_facets(jnp.float32),
-                             (6, 12, 24), (2, 4, 8), interpret=True)

@@ -13,9 +13,7 @@ Acceptance criteria pinned here:
 * the autotuner's storage axis (schema v4) caches, ranks and round-trips;
 * ``allocation.pack_all``/``unpack_into`` understand the deduplicated map,
   and the w | t restriction raises the documented ``ValueError`` from every
-  public entry point (the tile-dependent case routes to the sweep executor);
-* the ``facet_fetch`` Pallas read engine fetches via the owner-facet
-  indirection.
+  public entry point (the tile-dependent case routes to the sweep executor).
 """
 import warnings
 
@@ -522,62 +520,6 @@ def test_tile_dependent_modulo_routes_to_sweep_executor(storage):
     got, want = swp(x, dtype=jnp.float64), ref(x, dtype=jnp.float64)
     for k in want:
         assert (np.asarray(got[k]) == np.asarray(want[k])).all(), f"facet {k}"
-
-
-# ---------------------------------------------------------------------------
-# the facet_fetch read engine: owner-facet indirection
-# ---------------------------------------------------------------------------
-
-def test_facet_fetch_owner_indirection_bit_exact():
-    from repro.kernels.facet_fetch import fetch_interior_halos
-
-    name, space, tile = "jacobi2d5p", (8, 8, 8), (4, 4, 4)
-    pipe = CFAPipeline(get_program(name), IterSpace(space), Tiling(tile))
-    facets = pipe._sweep(_inputs(space, name), jnp.float32)
-    smap = build_storage_map(pipe.specs)
-    dd = dedup_facets(facets, smap)
-    h_red = fetch_interior_halos(name, facets, space, tile)
-    h_irr = fetch_interior_halos(name, dd, space, tile,
-                                 storage="irredundant")
-    assert (np.asarray(h_irr) == np.asarray(h_red)).all()
-    # the indirection is load-bearing: the redundant fetch over deduplicated
-    # arrays reads dead zeros
-    h_wrong = fetch_interior_halos(name, dd, space, tile)
-    assert not (np.asarray(h_wrong) == np.asarray(h_red)).all()
-
-
-def test_facet_fetch_rejects_compressed():
-    from repro.kernels.facet_fetch import fetch_interior_halos
-
-    name, space, tile = "jacobi2d5p", (8, 8, 8), (4, 4, 4)
-    pipe = CFAPipeline(get_program(name), IterSpace(space), Tiling(tile))
-    facets = pipe.init_facets(jnp.float32)
-    with pytest.raises(ValueError, match="decode"):
-        fetch_interior_halos(name, facets, space, tile, storage="compressed")
-
-
-@pytest.mark.parametrize("name,space,tile", [
-    ("jacobi2d9p", (8, 8, 8), (4, 4, 4)),
-    ("gaussian", (4, 16, 16), (2, 8, 8)),
-])
-def test_facet_fetch_owner_indirection_matches_copy_in(name, space, tile):
-    """The irredundant kernel fetch equals the irredundant pipeline's own
-    copy_in (the jnp owner-resolved gather) on interior tiles."""
-    from repro.kernels.facet_fetch import fetch_interior_halos
-
-    prog = get_program(name)
-    red = CFAPipeline(prog, IterSpace(space), Tiling(tile))
-    irr = IrredundantPipeline(prog, IterSpace(space), Tiling(tile))
-    facets = red._sweep(_inputs(space, name), jnp.float32)
-    dd = dedup_facets(facets, irr.storage_map)
-    H = fetch_interior_halos(name, dd, space, tile, storage="irredundant")
-    nt = red.num_tiles
-    for q0 in range(1, nt[0]):
-        for q1 in range(1, nt[1]):
-            for q2 in range(1, nt[2]):
-                want = irr.copy_in(dd, (q0, q1, q2))
-                got = H[q0 - 1, q1 - 1, q2 - 1]
-                assert (np.asarray(got) == np.asarray(want)).all(), (q0, q1, q2)
 
 
 # ---------------------------------------------------------------------------

@@ -179,41 +179,3 @@ def test_ssd_decode_step_consistent_with_scan():
             np.asarray(y_t), np.asarray(y_ref[:, t]), rtol=1e-5, atol=1e-5
         )
     np.testing.assert_allclose(np.asarray(S), np.asarray(s_ref), rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# facet-fetch read engine (paper Fig. 13 'read' stage as BlockSpec DMAs)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("name,space,tile", [
-    ("jacobi2d5p", (8, 8, 8), (4, 4, 4)),
-    ("jacobi2d9p", (12, 8, 8), (4, 4, 4)),
-    ("gaussian", (4, 16, 16), (2, 8, 8)),
-])
-def test_facet_fetch_kernel_matches_copy_in(name, space, tile):
-    from repro.core.cfa import CFAPipeline, IterSpace, Tiling, get_program
-    from repro.kernels.facet_fetch import (fetch_interior_halos,
-                                           fetch_interior_halos_ref)
-
-    prog = get_program(name)
-    pipe = CFAPipeline(prog, IterSpace(space), Tiling(tile))
-    rng = np.random.default_rng(0)
-    inputs = jnp.asarray(rng.normal(size=(pipe.specs[0].width, *space[1:])),
-                         jnp.float32)
-    facets = pipe._sweep(inputs, dtype=jnp.float32)
-    got = fetch_interior_halos(name, facets, space, tile, interpret=True)
-    want = fetch_interior_halos_ref(name, facets, space, tile)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_facet_fetch_rejects_non_dividing_width():
-    from repro.core.cfa import CFAPipeline, IterSpace, Tiling, get_program
-    from repro.kernels.facet_fetch import fetch_interior_halos
-
-    prog = get_program("smith-waterman-3seq")  # w0 = 3
-    pipe = CFAPipeline(prog, IterSpace((8, 8, 8)), Tiling((4, 4, 4)))
-    facets = pipe.init_facets(jnp.float32)
-    with pytest.raises(ValueError):
-        fetch_interior_halos("smith-waterman-3seq", facets, (8, 8, 8),
-                             (4, 4, 4))

@@ -294,21 +294,3 @@ def test_sweep_wavefront_sharded_kernel_path():
     for k in ref:
         np.testing.assert_allclose(np.asarray(ref[k]), np.asarray(got[k]),
                                    rtol=1e-12, atol=1e-12)
-
-
-def test_sharded_fetch_matches_plain_fetch():
-    """Port-resident facets feed the fetch kernel unchanged (placement moves
-    the DMAs to the owning port; the gathered halos are identical)."""
-    from repro.kernels.facet_fetch import (fetch_interior_halos,
-                                           fetch_interior_halos_sharded)
-
-    prog = get_program("jacobi2d5p")
-    space, tile = (12, 12, 12), (4, 4, 4)
-    pipe = CFAPipeline(prog, IterSpace(space), Tiling(tile))
-    rng = np.random.default_rng(3)
-    inputs = jnp.asarray(rng.normal(size=(1, 12, 12)))
-    facets = pipe._sweep(inputs, dtype=jnp.float64)
-    pa = assign_ports(IterSpace(space), prog.deps, Tiling(tile), 2)
-    plain = fetch_interior_halos("jacobi2d5p", facets, space, tile)
-    sharded = fetch_interior_halos_sharded("jacobi2d5p", facets, space, tile, pa)
-    assert (np.asarray(plain) == np.asarray(sharded)).all()
