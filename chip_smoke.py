@@ -140,10 +140,10 @@ def stencil_phase(space=STENCIL_SPACE, tile=STENCIL_TILE, *, seed: int = 0,
     log(f"[stencil] cold_wall_s={cold_s} warm_wall_s={warm_s} "
         f"warm_s_per_tile={warm_s / n_tiles} (host clock, smoke timing)")
 
-    deploy, scale = _hbm_filling_space(space, fbytes, HBM_BYTES / 2)
+    deploy, scale = _hbm_filling_space(space, fbytes, HBM_BYTES)
     log(f"[stencil] cut: an HBM-filling space at this tile is {deploy} "
-        f"(~{fbytes * scale} facet bytes, half of HBM since each eager "
-        f"copy_out holds the old and the new facet array); run at {space} "
+        f"(~{fbytes * scale} facet bytes; copy_out updates the donated "
+        f"facet arrays in place); run at {space} "
         f"because every tile is dispatched from the host (ROADMAP S2): "
         f"~{n_tiles * scale} tiles at {warm_s / n_tiles} s each would take "
         f"~{n_tiles * scale * warm_s / n_tiles} s per sweep")

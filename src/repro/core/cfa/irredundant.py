@@ -24,7 +24,8 @@ storage discipline as a first-class subsystem:
 * :class:`IrredundantPipeline` — a ``CFAPipeline`` whose ``copy_out``
   commits only owned slots and whose ``copy_in`` resolves every halo read
   to the owner facet's storage (the owner-facet indirection; the compiled
-  fetch's ``FetchPlan`` bakes the same owner reads into its tables).
+  fetch's ``FetchPlan`` bakes the same owner reads into its tables, and
+  the compiled commit traces the owner mask in as a constant).
 * :class:`CompressedPipeline` — additionally passes every committed block
   through a fixed-ratio :class:`~repro.core.cfa.compress.BlockCodec`
   round-trip, so results reflect exactly what compressed storage preserved
@@ -42,6 +43,7 @@ import math
 from typing import ClassVar, Mapping
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .compress import BlockCodec, get_codec
@@ -258,7 +260,7 @@ class IrredundantPipeline(CFAPipeline):
 
     Same facet shapes, same schedule, two overrides:
 
-    * ``copy_out`` (via ``_store_block``) commits only owned slots — a
+    * ``copy_out`` (via ``_commit_block``) commits only owned slots — a
       value is written exactly once, to its owner facet;
     * ``copy_in`` (via ``_halo_hosts``) reads every halo point from its
       owner facet, whether or not that facet's axis is crossed — the
@@ -291,9 +293,14 @@ class IrredundantPipeline(CFAPipeline):
         mask = self.storage_map.owned[spec.axis]
         if mask.all():
             return super()._commit_block(arr, idx, block, spec)
-        # owned slots get the new value; non-owned slots stay untouched
-        # (the mask covers the inner dims, so every field alike)
-        return arr.at[idx].set(jnp.where(jnp.asarray(mask), block, arr[idx]))
+        # owned slots get the new value; non-owned slots keep the old one
+        # (the mask covers the inner dims, so every field alike).  The mask
+        # is the same for every tile: a constant of the compiled commit
+        n = len(idx)
+        old = jax.lax.dynamic_slice(arr, (*idx, *(0,) * (arr.ndim - n)),
+                                    (1,) * n + arr.shape[n:])
+        return super()._commit_block(
+            arr, idx, jnp.where(mask, block, old.reshape(arr.shape[n:])), spec)
 
 
 @dataclasses.dataclass

@@ -20,9 +20,10 @@ attributable timeline:
   profiler session sees the phases on the device trace's clock, plus the
   recorder span of the same name when a recorder is attached.
 * :class:`Counters` — a deterministic metrics registry (bursts issued,
-  wire vs stored bytes, tiles, waves, halo indirections, the fetch and
-  execute paths each tile took: ``fetch_compiled`` / ``fetch_eager``,
-  ``execute_compiled`` / ``execute_eager``) whose totals
+  wire vs stored bytes, tiles, waves, halo indirections, the fetch,
+  execute and commit paths each tile took: ``fetch_compiled`` /
+  ``fetch_eager``, ``execute_compiled`` / ``execute_eager``,
+  ``commit_compiled`` / ``commit_eager``) whose totals
   :meth:`TraceRecorder.reconcile` checks *exactly* against
   ``BurstModel.plan_bytes`` and the per-tile plans' read/write
   accounting — the runtime counterpart of the CFA1xx static verifier.
@@ -433,7 +434,8 @@ class TraceRecorder:
         * ``wire_bytes_read + wire_bytes_write`` — equals the sum of
           ``model.plan_bytes(tile_plan)`` over all tiles, exactly;
         * span population — one ``copy_in`` and one ``copy_out`` span per
-          tile, grouped per wave.
+          tile, grouped per wave;
+        * ``commits`` — one ``commit_compiled`` or ``commit_eager`` a tile.
 
         Returns ``{"ok": bool, "expected": {...}, "observed": {...},
         "mismatches": [...]}``.
@@ -465,8 +467,11 @@ class TraceRecorder:
                     model.burst_bytes(r, plan.codec_bits) for r in plan.write_runs)
                 exp["plan_bytes"] += model.plan_bytes(plan)
         exp["waves"] = len(per_wave)
+        exp["commits"] = exp["tiles"]
 
         obs = {k: self.counters.get(k) for k in exp}
+        obs["commits"] = (self.counters.get("commit_compiled")
+                          + self.counters.get("commit_eager"))
         obs["plan_bytes"] = (self.counters.get("wire_bytes_read")
                             + self.counters.get("wire_bytes_write")) \
             if model is not None else 0.0

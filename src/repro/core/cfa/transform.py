@@ -9,7 +9,8 @@ form), a rectangular space and a tiling, :class:`CFAPipeline` provides
   buffer (the on-chip scratchpad; off-chip side reads facet blocks),
 * ``execute_tile`` — run the tile's plane recurrence on the halo buffer,
 * ``copy_out``     — write the tile's facet blocks (full-tile contiguity:
-  each is one contiguous store),
+  each is one contiguous store), one compiled program a tile over the
+  pipeline's :class:`CommitPlan`,
 * ``_sweep``       — the whole accelerator loop over tiles in lexicographic
   order (the legal schedule under backward dependences); the executor
   registry (``repro.core.cfa.executors``) is the public way to run it.
@@ -22,8 +23,11 @@ program a tile: ``copy_in`` reads the tile's row of the pipeline's
 :class:`FetchPlan`, gather and scatter tables resolved once and kept on
 the device.  The wavefront executor runs a wave's plane recurrences as
 one compiled program (``execute_wave``); the ``sweep`` oracle and the
-dataflow host path keep the eager ``execute_tile``.  The commit is still
-a chain of eager programs.
+dataflow host path keep the eager ``execute_tile``.  The commit is one
+compiled program a tile too: ``copy_out`` reads the tile's row of the
+pipeline's :class:`CommitPlan`, block indices and permutations resolved
+once and kept on the device, and updates the donated facet arrays in
+place; ``load_inputs`` stores the live-in row through the same plan.
 
 The pipeline is dimension-generic (the paper's construction is, §IV-F..J):
 any d >= 2 works — one time axis plus d-1 spatial axes — so 2-D programs
@@ -57,7 +61,7 @@ from .facets import FacetSpec, build_facet_specs, row_major_strides
 from .programs import StencilProgram
 from .spaces import IterSpace, Tiling, box_points
 
-__all__ = ["CFAPipeline", "FetchPlan"]
+__all__ = ["CFAPipeline", "CommitPlan", "FetchPlan"]
 
 
 def device_index(offsets: np.ndarray) -> jnp.ndarray:
@@ -98,6 +102,33 @@ class FetchPlan:
     src: tuple
     dst: tuple
     shape: tuple[int, ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class CommitPlan:
+    """Every tile's facet commit of one pipeline, resolved once.
+
+    ``index[i]`` and ``perm[i]`` are the tables of facet array
+    ``keys[i]``: row ``r`` holds the outer index of the block that row
+    writes (``(n_rows, n_outer)``) and the permutation along the facet's
+    axis that puts the slab in the modulo labelling (``(n_rows, w)``).
+    ``rows[tile]`` is a tile's row in every table.  Facet_0's tables
+    carry one row more per block of its virtual live-in row, after the
+    tiles' rows, in the order :meth:`CFAPipeline._live_in_blocks` gives.
+
+    ``commit`` and ``load`` are the plan's two programs (``None`` in the
+    host tables of :meth:`CFAPipeline.commit_tables`): one tile's commit
+    over ``(facets, index, perm, row, H)`` and the whole live-in row over
+    ``(facet_0, index[0], perm[0], inputs)``, each jitted once per
+    pipeline with the facet arrays donated.
+    """
+
+    rows: Mapping[tuple[int, ...], object]
+    keys: tuple[int, ...]
+    index: tuple
+    perm: tuple
+    commit: typing.Callable | None = None
+    load: typing.Callable | None = None
 
 
 def _pad_rows(pieces: list[list[np.ndarray]], fill: int) -> np.ndarray:
@@ -170,6 +201,10 @@ class CFAPipeline:
     # the compiled fetch's tables, built on the first single-device copy_in
     _fetch_plan: "FetchPlan | None" = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
+    # the compiled commit's tables and programs, built on the first
+    # single-device load_inputs or copy_out
+    _commit_plan: "CommitPlan | None" = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
     # the compiled execute, built on the first execute_wave
     _wave_program: typing.Callable | None = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
@@ -219,56 +254,81 @@ class CFAPipeline:
         self, facets: dict[int, jnp.ndarray], inputs: jnp.ndarray
     ) -> dict[int, jnp.ndarray]:
         """Pack live-in planes (w_0, [F,] N_1, .., N_{d-1}) into the virtual
-        facet_0 row."""
-        spec = self.specs[0]
-        w0 = spec.width
-        want = self.program.with_fields((w0, *self.space.sizes[1:]), self.fields)
+        facet_0 row.
+
+        Facets on one device take the :class:`CommitPlan`'s ``load``
+        program: every live-in block in one program, facet_0 donated, so
+        the facet_0 passed in is deleted.  Facets over several devices
+        store block by block through the eager :meth:`_store_block`."""
+        want = self.program.with_fields(
+            (self.specs[0].width, *self.space.sizes[1:]), self.fields)
         if inputs.shape != want:
             raise ValueError(f"inputs must be {want}")
-        f0 = facets[0]
+        facets = dict(facets)
+        if _on_one_device(facets):
+            plan = self._commit_plan or self._build_commit_plan()
+            facets[0] = plan.load(facets[0], plan.index[0], plan.perm[0],
+                                  inputs)
+            return facets
+        for q, sl in self._live_in_blocks():
+            facets[0] = self._store_block(facets[0], self.specs[0], (-1, *q),
+                                          inputs[sl])
+        return facets
+
+    def _live_in_blocks(self):
+        """Each block of facet_0's virtual row: its spatial tile index and
+        its slice of the live-in planes (every field)."""
         t = self.tiling.sizes
         for q in itertools.product(*(range(n) for n in self.num_tiles[1:])):
-            sl = tuple(
-                slice(q[a - 1] * t[a], (q[a - 1] + 1) * t[a])
-                for a in range(1, self.space.ndim)
-            )
-            blk = inputs[self.program.with_fields((slice(None), *sl), slice(None))]
-            f0 = self._store_block(f0, spec, (-1, *q), blk, virtual=True)
-        facets = dict(facets)
-        facets[0] = f0
-        return facets
+            sl = tuple(slice(qa * ta, (qa + 1) * ta) for qa, ta in zip(q, t[1:]))
+            yield q, self.program.with_fields((slice(None), *sl), slice(None))
 
     # -- block addressing ----------------------------------------------------
 
-    def _block_index(self, spec: FacetSpec, tile: tuple[int, ...], virtual: bool):
-        idx = []
-        for a in spec.outer_axes:
-            q = tile[a]
-            if spec.axis == 0 and a == 0:
-                q += 1  # shift for the virtual live-in row
-            idx.append(q)
-        return tuple(idx)
+    def _block_index(self, spec: FacetSpec, tile: tuple[int, ...]) -> tuple[int, ...]:
+        """Outer index of the tile's block in facet ``spec``; facet_0's
+        blocks sit one row down, below the virtual live-in row (tile
+        ``(-1, *q)``)."""
+        return tuple(tile[a] + (spec.axis == 0 and a == 0)
+                     for a in spec.outer_axes)
 
-    def _store_block(self, arr, spec: FacetSpec, tile, slab, *, virtual=False):
+    def _block_perm(self, spec: FacetSpec, tile: tuple[int, ...]) -> np.ndarray:
+        """The paper's modulo labelling of the block (tile-dependent, in
+        general): block position ``m`` along ``spec.axis`` holds slab
+        position ``perm[m]``.  The live-in tile ``(-1, *q)`` starts at
+        ``-w``."""
+        k, w, t_k = spec.axis, spec.width, spec.tile_sizes[spec.axis]
+        x0 = tile[k] * t_k + t_k - w
+        return np.argsort([(x0 + j) % w for j in range(w)])
+
+    def _store_block(self, arr, spec: FacetSpec, tile, slab):
+        """Store one slab, eagerly: the block index and permutation are
+        resolved on the host and uploaded with the call.  The commit of
+        facets over several devices, and the oracle of the compiled one."""
+        return self._put_block(arr, spec, self._block_index(spec, tile),
+                               jnp.asarray(self._block_perm(spec, tile)), slab)
+
+    def _put_block(self, arr, spec: FacetSpec, idx, perm, slab):
         """``slab`` has canonical axis order (the field axis after time)
         with axis ``spec.axis`` of size w indexed by slab position; store it
-        permuted to the facet block layout with the paper's
-        (tile-dependent, in general) modulo labelling."""
-        k, w, t_k = spec.axis, spec.width, spec.tile_sizes[spec.axis]
-        x0 = tile[k] * t_k + t_k - w if not virtual else -w
-        perm = np.argsort([(x0 + j) % w for j in range(w)])  # m -> slab j
-        slab = jnp.take(slab, jnp.asarray(perm), axis=self._array_axis(k))
+        permuted by ``perm`` and transposed to the facet block layout at
+        outer index ``idx``.  Traceable: the compiled commit calls it with
+        its tables' rows, :meth:`_store_block` with host values."""
+        slab = jnp.take(slab, perm, axis=self._array_axis(spec.axis))
         order = [self._array_axis(a) for a in spec.inner_axes]
         block = slab.transpose([1, *order] if self.program.fields else order)
-        return self._commit_block(arr, self._block_index(spec, tile, virtual),
-                                  block, spec)
+        return self._commit_block(arr, idx, block, spec)
 
     def _commit_block(self, arr, idx, block, spec: FacetSpec):
-        """Write one laid-out facet block at its outer index.  The storage
-        disciplines override only this commit step (owner-masked under
-        irredundant storage, codec round-trip under compressed — see
-        ``repro.core.cfa.irredundant``)."""
-        return arr.at[idx].set(block)
+        """Write one laid-out facet block at its outer index: one
+        contiguous ``dynamic_update_slice``.  The storage disciplines
+        override only this commit step (owner-masked under irredundant
+        storage, codec round-trip under compressed — see
+        ``repro.core.cfa.irredundant``); it is traced inside the compiled
+        commit, so an override uses ``jnp`` and ``lax`` only."""
+        block = block.astype(arr.dtype).reshape((1,) * len(idx) + block.shape)
+        return jax.lax.dynamic_update_slice(arr, block,
+                                            (*idx, *(0,) * (block.ndim - len(idx))))
 
     # -- copy-in -------------------------------------------------------------
 
@@ -520,18 +580,93 @@ class CFAPipeline:
     def copy_out(
         self, facets: dict[int, jnp.ndarray], tile: tuple[int, ...], H: jnp.ndarray
     ) -> dict[int, jnp.ndarray]:
+        """Write the tile's facet blocks from the interior of its filled
+        halo buffer ``H``: per facet array, the last ``w`` planes of the
+        interior along the facet's axis, laid out as one block.
+
+        Facets on one device take the compiled commit: the pipeline's
+        :class:`CommitPlan`, built on the first such call, holds every
+        tile's block indices and permutations on the device, and one
+        jitted program per pipeline stores the tile's blocks with the
+        facet arrays donated (updated in place; the arrays passed in are
+        deleted, so a caller keeps only the returned dict).  Facets over
+        several devices take the eager :meth:`_store_block`, block by
+        block.  Where the facets sit is the only choice.
+        """
         rec = self.recorder
+        compiled = _on_one_device(facets)
         with obs.phase(rec, "copy_out", "commit", fields=self.fields,
                        after=lambda: rec.record_write(self, tile)):
-            w = self.widths
-            t = self.tiling.sizes
-            interior = H[self._interior_slices(w)]
+            if rec is not None:
+                rec.counters.add("commit_compiled" if compiled else "commit_eager", 1)
             out = dict(facets)
+            if compiled:
+                plan = self._commit_plan or self._build_commit_plan()
+                out.update(zip(plan.keys, plan.commit(
+                    tuple(facets[k] for k in plan.keys), plan.index,
+                    plan.perm, plan.rows[tile], H)))
+                return out
             for k, spec in self.specs.items():
-                sl = [slice(None)] * interior.ndim
-                sl[self._array_axis(k)] = slice(t[k] - spec.width, t[k])
-                out[k] = self._store_block(out[k], spec, tile, interior[tuple(sl)])
+                out[k] = self._store_block(out[k], spec, tile,
+                                           self._commit_slab(H, k))
         return out
+
+    def _commit_slab(self, H: jnp.ndarray, k: int) -> jnp.ndarray:
+        """The slab facet ``k`` stores of a filled halo buffer: the
+        interior's last ``w_k`` planes along axis ``k`` (every field)."""
+        sl = list(self._interior_slices(self.widths))
+        # the interior starts at w_k, so its last w_k planes at t_k
+        sl[self._array_axis(k)] = slice(self.tiling.sizes[k], None)
+        return H[tuple(sl)]
+
+    # -- compiled commit ---------------------------------------------------------
+
+    def commit_tables(self) -> "CommitPlan":
+        """Resolve every block store once, on the host: the
+        :class:`CommitPlan` with numpy tables and each tile's row number
+        (no programs).  Rows follow the tiles in lexicographic order;
+        facet_0's live-in rows come after them."""
+        tiles = list(itertools.product(*(range(n) for n in self.num_tiles)))
+        live = [(-1, *q) for q, _ in self._live_in_blocks()]
+        index, perm = [], []
+        for k, spec in self.specs.items():
+            blocks = tiles + live if k == 0 else tiles
+            index.append(np.array([self._block_index(spec, b) for b in blocks],
+                                  np.int64).reshape(len(blocks), -1))
+            perm.append(np.array([self._block_perm(spec, b) for b in blocks],
+                                 np.int64))
+        return CommitPlan(
+            rows={tile: r for r, tile in enumerate(tiles)},
+            keys=tuple(self.specs), index=tuple(index), perm=tuple(perm))
+
+    def _build_commit_plan(self) -> "CommitPlan":
+        """Build this pipeline's commit plan: upload its tables and row
+        numbers, each once, through :func:`device_index`, and jit its two
+        programs, the facet arrays donated."""
+        plan = self.commit_tables()
+        rows = list(device_index(np.arange(len(plan.rows))))
+
+        def commit(facets, index, perm, row, H):
+            return tuple(
+                self._put_block(arr, self.specs[k], tuple(ix[row]), pm[row],
+                                self._commit_slab(H, k))
+                for arr, k, ix, pm in zip(facets, plan.keys, index, perm))
+
+        def load(f0, index, perm, inputs):
+            for r, (_, sl) in enumerate(self._live_in_blocks(), len(plan.rows)):
+                f0 = self._put_block(f0, self.specs[0], tuple(index[r]),
+                                     perm[r], inputs[sl])
+            return f0
+
+        self._commit_plan = dataclasses.replace(
+            plan,
+            rows={tile: rows[r] for tile, r in plan.rows.items()},
+            index=tuple(device_index(ix) for ix in plan.index),
+            perm=tuple(device_index(pm) for pm in plan.perm),
+            commit=jax.jit(commit, donate_argnums=0),
+            load=jax.jit(load, donate_argnums=0),
+        )
+        return self._commit_plan
 
     # -- full sweep ----------------------------------------------------------------
 

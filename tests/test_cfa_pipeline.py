@@ -132,6 +132,12 @@ def test_copy_in_gathers_through_the_offset_guard(monkeypatch):
                        Tiling((4, 4, 4)))
     facets = pipe.load_inputs(pipe.init_facets(jnp.float32),
                               jnp.ones((1, 8, 8), jnp.float32))
+    # the commit plan's uploads: the row numbers, the block indices and
+    # the permutations, one table each per facet array
+    commit = pipe._commit_plan
+    assert seen == ([(8,)] + [ix.shape for ix in commit.index]
+                    + [pm.shape for pm in commit.perm])
+    seen.clear()
     pipe.copy_in(facets, (0, 0, 0))  # live-in row only: builds the plan
     plan = pipe._fetch_plan
     assert plan.keys == (0, 1, 2)

@@ -40,6 +40,9 @@ SPACES = {
     "gaussian": ((4, 16, 16), (2, 8, 8)),
     # a history of depth 3 along time
     "smith-waterman-3seq": ((9, 8, 8), (3, 4, 4)),
+    # tiles that width-2 facets do not divide: the modulo labelling of a
+    # block depends on the tile
+    "jacobi2d9p-gol": ((8, 12, 10), (4, 3, 5)),
 }
 STORAGES = {"redundant": CFAPipeline, "irredundant": IrredundantPipeline}
 CASES = [pytest.param(name, storage, id=f"{name}-{storage}")
@@ -112,6 +115,7 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
     jax.config.update("jax_enable_x64", True)
+    import jax.numpy as jnp
     import numpy as np
     sys.path.insert(0, sys.argv[1])
     import test_fetch_plan as t
@@ -134,20 +138,38 @@ _MULTI_DEVICE_SCRIPT = textwrap.dedent("""
             got = [pipe.copy_in(spread, tile) for tile in t._tiles(pipe)]
             eager = rec.counters.get("fetch_eager")
             want = [pipe.copy_in(one, tile) for tile in t._tiles(pipe)]
-            out[f"{name}-{storage}"] = dict(
+            fetch = dict(
                 tiles=len(got), eager=eager,
                 compiled=rec.counters.get("fetch_compiled"),
                 exact=all(np.array_equal(np.asarray(g), np.asarray(w))
                           for g, w in zip(got, want)))
+            # every tile's commit of one random halo buffer, to the spread
+            # facets and then to the one-device facets (which it donates)
+            rec = obs.TraceRecorder()
+            pipe.recorder = rec
+            halos = [rng.normal(size=pipe.halo_shape) for _ in t._tiles(pipe)]
+            for tile, H in zip(t._tiles(pipe), halos):
+                spread = pipe.copy_out(spread, tile, jnp.asarray(H))
+            eager = rec.counters.get("commit_eager")
+            for tile, H in zip(t._tiles(pipe), halos):
+                one = pipe.copy_out(one, tile, jnp.asarray(H))
+            commit = dict(
+                tiles=len(halos), eager=eager,
+                compiled=rec.counters.get("commit_compiled"),
+                devices=len(set().union(*(v.devices() for v in spread.values()))),
+                exact=all(np.array_equal(np.asarray(spread[k]), np.asarray(one[k]))
+                          for k in pipe.specs))
+            out[f"{name}-{storage}"] = dict(fetch=fetch, commit=commit)
     print("MULTI_DEVICE " + json.dumps(out))
 """)
 
 
-@pytest.fixture(scope="module")
-def multi_device_fetches():
-    """Every tile's fetch from facets spread over two of four forced host
-    devices, then from the same facets on one device; all cases in one
-    subprocess (the test session has one CPU device)."""
+@pytest.fixture(scope="session")
+def multi_device_runs():
+    """Every tile's fetch, then every tile's commit, on facets spread over
+    two of four forced host devices and on the same facets on one device;
+    all cases in one subprocess (the test session has one CPU device).
+    ``tests/test_commit_plan.py`` reads the commits."""
     from conftest import multidevice_emulation_reason
 
     reason = multidevice_emulation_reason()
@@ -164,10 +186,10 @@ def multi_device_fetches():
 
 @pytest.mark.parametrize("name,storage", CASES)
 def test_multi_device_facets_take_the_eager_fetch(name, storage,
-                                                  multi_device_fetches):
+                                                  multi_device_runs):
     """Facets over two devices keep the host-buffer gather on every tile,
     and the halos equal the compiled fetch's from one device."""
-    got = multi_device_fetches[f"{name}-{storage}"]
+    got = multi_device_runs[f"{name}-{storage}"]["fetch"]
     n = got["tiles"]
     # the spread fetches all eager; the one-device fetches after them compiled
     assert (got["eager"], got["compiled"]) == (n, n)
