@@ -359,12 +359,15 @@ class TraceRecorder:
 
     def tile_plan(self, pipeline, tile: tuple[int, ...]):
         """The exact :class:`TransferPlan` of ``tile`` under the
-        pipeline's layout knobs (cached per tile; boundary tiles have
-        smaller flow-in than the interior plan)."""
-        key = tuple(int(x) for x in tile)
+        pipeline's layout knobs (boundary tiles have smaller flow-in than
+        the interior plan).  Tiles of one halo class
+        (:meth:`~repro.core.cfa.transform.CFAPipeline.halo_class`) move
+        the same runs at translated addresses, so it is computed once a
+        class and cached."""
+        key = pipeline.halo_class(tile)
         plan = self._plan_cache.get(key)
         if plan is None:
-            plan = _pipeline_tile_plan(pipeline, key)
+            plan = _pipeline_tile_plan(pipeline, tuple(int(x) for x in tile))
             self._plan_cache[key] = plan
         return plan
 

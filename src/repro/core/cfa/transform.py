@@ -19,15 +19,17 @@ On real hardware the three phases run as a coarse-grain pipeline
 (paper Fig. 13, DATAFLOW); in Pallas the same overlap comes for free from
 grid pipelining — see ``repro.kernels.stencil``.  The executors here
 still dispatch tile by tile from the host.  The fetch is one compiled
-program a tile: ``copy_in`` reads the tile's row of the pipeline's
-:class:`FetchPlan`, gather and scatter tables resolved once and kept on
-the device.  The wavefront executor runs a wave's plane recurrences as
-one compiled program (``execute_wave``); the ``sweep`` oracle and the
-dataflow host path keep the eager ``execute_tile``.  The commit is one
-compiled program a tile too: ``copy_out`` reads the tile's row of the
-pipeline's :class:`CommitPlan`, block indices and permutations resolved
-once and kept on the device, and updates the donated facet arrays in
-place; ``load_inputs`` stores the live-in row through the same plan.
+program a tile: ``copy_in`` reads the tile's rows of the pipeline's
+:class:`FetchPlan`, gather and scatter tables resolved once per class of
+tiles whose halos are translates of one another, kept on the device with
+each tile's class and shift.  The wavefront executor runs a wave's plane
+recurrences as one compiled program (``execute_wave``); the ``sweep``
+oracle and the dataflow host path keep the eager ``execute_tile``.  The
+commit is one compiled program a tile too: ``copy_out`` reads the tile's
+row of the pipeline's :class:`CommitPlan`, block indices and permutations
+resolved once and kept on the device, and updates the donated facet
+arrays in place; ``load_inputs`` stores the live-in row through the same
+plan.
 
 The pipeline is dimension-generic (the paper's construction is, §IV-F..J):
 any d >= 2 works — one time axis plus d-1 spatial axes — so 2-D programs
@@ -64,6 +66,18 @@ from .spaces import IterSpace, Tiling, box_points
 __all__ = ["CFAPipeline", "CommitPlan", "FetchPlan"]
 
 
+def _check_index(top: int) -> None:
+    """Raise where a flat offset ``top`` does not fit the index dtype JAX
+    uses (int32 unless x64 is enabled, as it is not on the chip)."""
+    dtype = jax.dtypes.canonicalize_dtype(np.int64)
+    if top > np.iinfo(dtype).max:
+        raise OverflowError(
+            f"gather offset {top} does not fit JAX's index "
+            f"dtype {np.dtype(dtype).name}; the facet array is too large to "
+            "address without 64-bit indices (jax_enable_x64)"
+        )
+
+
 def device_index(offsets: np.ndarray) -> jnp.ndarray:
     """Upload host-computed flat gather offsets in the index dtype JAX uses
     (int32 unless x64 is enabled, as it is not on the chip).
@@ -73,35 +87,55 @@ def device_index(offsets: np.ndarray) -> jnp.ndarray:
     2**31 elements would return wrong values silently.  Such an offset
     raises here instead.
     """
-    dtype = jax.dtypes.canonicalize_dtype(np.int64)
-    if offsets.size and int(offsets.max()) > np.iinfo(dtype).max:
-        raise OverflowError(
-            f"gather offset {int(offsets.max())} does not fit JAX's index "
-            f"dtype {np.dtype(dtype).name}; the facet array is too large to "
-            "address without 64-bit indices (jax_enable_x64)"
-        )
-    return jnp.asarray(offsets, dtype)
+    if offsets.size:
+        _check_index(int(offsets.max()))
+    return jnp.asarray(offsets, jax.dtypes.canonicalize_dtype(np.int64))
 
 
 @dataclasses.dataclass(frozen=True)
 class FetchPlan:
-    """Every tile's halo gather of one pipeline, resolved once.
+    """Every tile's halo gather of one pipeline, resolved once per class
+    of tiles.
 
-    ``maps[tile]`` is the tile's resolved halo (``CFAPipeline._halo_maps``)
-    and ``rows[tile]`` its row in the tables.  ``src[i]`` and ``dst[i]``
-    are ``(n_tiles, max_points)`` tables for facet array ``keys[i]``: row
-    ``r`` holds the flat facet offsets tile ``r`` reads and the flat
-    offsets in the ``shape`` halo buffer they land at.  A short row is
-    padded with source 0 and destination ``prod(shape)``, one past the
-    buffer's end, which the scatter drops.
+    Tiles whose halos are translates of one another (the same sides on
+    the space's lower boundary, the same modulo phase of each facet) form
+    a halo class: ``maps[c]`` is the resolved halo
+    (``CFAPipeline._halo_maps``) of class ``c``'s first tile, and
+    ``rows[tile]`` is ``(r, c)``, the tile's row in ``cls`` and ``shift``
+    and its halo class.
+
+    ``src[i]`` and ``dst[i]`` are the tables of facet array ``keys[i]``,
+    one row per distinct read pattern: the flat facet offsets a tile
+    reads, relative to its anchor block
+    (:meth:`CFAPipeline._fetch_anchors`), and the flat offsets in the
+    ``shape`` halo buffer they land at.  The tile in row ``r`` gathers at
+    ``src[i][cls[r, i]] + shift[r, i]``, ``shift`` being the flat offset
+    of its anchor block.  A short row is padded with source 0, the anchor
+    block's first element (in bounds for every tile), and destination
+    ``prod(shape)``, one past the buffer's end, which the scatter drops.
     """
 
-    maps: Mapping[tuple[int, ...], Mapping]
-    rows: Mapping[tuple[int, ...], object]
+    maps: tuple
+    rows: Mapping[tuple[int, ...], tuple]
     keys: tuple[int, ...]
     src: tuple
     dst: tuple
+    cls: typing.Any
+    shift: typing.Any
     shape: tuple[int, ...]
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes the tables hold (on the device once uploaded)."""
+        return sum(int(a.nbytes) for a in (*self.src, *self.dst, self.cls, self.shift))
+
+    def reach(self) -> int:
+        """The largest flat offset any tile gathers at, pads included
+        (host tables): what the index dtype has to hold."""
+        top = 0
+        for i, s in enumerate(self.src):
+            top = max(top, int((s.max(axis=1)[self.cls[:, i]] + self.shift[:, i]).max()))
+        return top
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,9 +165,8 @@ class CommitPlan:
     load: typing.Callable | None = None
 
 
-def _pad_rows(pieces: list[list[np.ndarray]], fill: int) -> np.ndarray:
-    """One table row per tile, its pieces end to end; short rows padded."""
-    rows = [np.concatenate(p) if p else np.zeros(0, np.int64) for p in pieces]
+def _pad_rows(rows: list[np.ndarray], fill: int) -> np.ndarray:
+    """The rows as one table, short rows padded with ``fill``."""
     out = np.full((len(rows), max(len(r) for r in rows)), fill, np.int64)
     for r, row in enumerate(rows):
         out[r, :len(row)] = row
@@ -159,19 +192,21 @@ def _unravel(flat, shape: tuple[int, ...]) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
-def _fetch_halo(facets, src, dst, row, *, shape):
+def _fetch_halo(facets, src, dst, cls, shift, row, *, shape):
     """One tile's halo buffer from the fetch plan's tables: per facet
-    array, gather the tile's row of source offsets and scatter the values
-    to its row of destinations in a zero buffer; pads are dropped.
+    array, gather at the tile's class row of source offsets plus its
+    shift and scatter the values to the class row of destinations in a
+    zero buffer; pads are dropped.
 
     The gather indexes each facet in its own shape: a flat view of a
     facet would make the TPU relayout the whole array first (its minor
     dims are tiled and padded), once per tile."""
     H = jnp.zeros(math.prod(shape), facets[0].dtype)
-    for f, s, d in zip(facets, src, dst):
-        vals = f.at[_unravel(s[row], f.shape)].get(
+    for i, (f, s, d) in enumerate(zip(facets, src, dst)):
+        c = cls[row, i]
+        vals = f.at[_unravel(s[c] + shift[row, i], f.shape)].get(
             mode="promise_in_bounds", wrap_negative_indices=False)
-        H = H.at[d[row]].set(vals, mode="drop", wrap_negative_indices=False)
+        H = H.at[d[c]].set(vals, mode="drop", wrap_negative_indices=False)
     return H.reshape(shape)
 
 
@@ -198,7 +233,8 @@ class CFAPipeline:
     recorder: object | None = dataclasses.field(default=None, repr=False, compare=False)
     specs: Mapping[int, FacetSpec] = dataclasses.field(init=False)
     num_tiles: tuple[int, ...] = dataclasses.field(init=False)
-    # the compiled fetch's tables, built on the first single-device copy_in
+    # the compiled fetch's tables, built on the first single-device sweep
+    # or copy_in
     _fetch_plan: "FetchPlan | None" = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
     # the compiled commit's tables and programs, built on the first
@@ -382,9 +418,10 @@ class CFAPipeline:
         """Gather the tile's flow-in into a halo buffer of shape (w + t).
 
         Facets on one device take the compiled fetch: the pipeline's
-        :class:`FetchPlan`, built on the first such call, holds every
-        tile's halo maps and device-resident gather/scatter tables, and one
-        jitted program per pipeline reads the tile's row of them.  Facets
+        :class:`FetchPlan`, built on the first such call (or at the start
+        of a sweep), holds every halo class's maps and device-resident
+        gather/scatter tables and every tile's class and shift, and one
+        jitted program per pipeline reads the tile's rows of them.  Facets
         over several devices (port-resident facets under the ``sharded``
         executor) take the eager, piece-by-piece gather of
         :meth:`_gather_halo`.  Where the facets sit is the only choice.
@@ -400,15 +437,16 @@ class CFAPipeline:
                                               **rec.record_halo(self, maps))):
                 if compiled:
                     plan = self._fetch_plan or self._build_fetch_plan()
-                    maps = plan.maps[tile]
+                    row, c = plan.rows[tile]
+                    maps = plan.maps[c]
                 else:
                     maps, lo, w = self._halo_maps(tile)
             if rec is not None:
                 rec.counters.add("fetch_compiled" if compiled else "fetch_eager", 1)
             if compiled:
                 return _fetch_halo(tuple(facets[k] for k in plan.keys),
-                                   plan.src, plan.dst, plan.rows[tile],
-                                   shape=plan.shape)
+                                   plan.src, plan.dst, plan.cls, plan.shift,
+                                   row, shape=plan.shape)
             return self._gather_halo(facets, maps, lo, w)
 
     def _source_offsets(self, key, pts: np.ndarray) -> np.ndarray:
@@ -481,52 +519,161 @@ class CFAPipeline:
 
     # -- compiled fetch --------------------------------------------------------
 
-    def fetch_tables(self) -> "FetchPlan":
-        """Resolve every tile's halo once, on the host: the
-        :class:`FetchPlan` with numpy tables and each tile's row number.
+    def _tile_array(self) -> np.ndarray:
+        """Every tile's coordinates, one row a tile in lexicographic
+        order."""
+        grid = np.indices(self.num_tiles, dtype=np.int64)
+        return grid.reshape(self.space.ndim, -1).T
 
-        The facet shapes, the storage discipline (:meth:`_halo_hosts`) and
-        the tile set are fixed for a pipeline instance, so the plan is
-        too.  Live-in points go into facet_0's table, beside its real
-        rows."""
-        tiles = list(itertools.product(*(range(n) for n in self.num_tiles)))
-        shape = self.halo_shape
-        strides = row_major_strides(shape)
-        maps_of = {}
-        # per facet array, per tile: the pieces of its source and
-        # destination offsets
-        src: dict[int, list[list[np.ndarray]]] = {k: [] for k in self.specs}
-        dst: dict[int, list[list[np.ndarray]]] = {k: [] for k in self.specs}
+    def _halo_classes(self, tiles: np.ndarray) -> np.ndarray:
+        """One row a tile: on each axis that carries a facet, whether the
+        tile is the first along it (its halo box then crosses the space's
+        lower boundary, or reads the live-in row on time), then each
+        facet's modulo phase ``t_a * q_a mod w_a``.  Tiles with equal
+        rows have halos, fetch rows and transfer plans that are
+        translates of one another."""
+        t = np.asarray(self.tiling.sizes, np.int64)
+        w = np.asarray(self.widths, np.int64)
+        return np.concatenate([(tiles == 0) & (w > 0), tiles * t % np.maximum(w, 1)],
+                              axis=1)
+
+    def halo_class(self, tile: tuple[int, ...]) -> tuple[int, ...]:
+        """The halo class of one tile (:meth:`_halo_classes`)."""
+        row = self._halo_classes(np.asarray(tile, np.int64)[None])[0]
+        return tuple(int(v) for v in row)
+
+    def _fetch_anchors(self, tiles: np.ndarray) -> dict[int, np.ndarray]:
+        """Per facet array, the flat offset of each tile's anchor block:
+        the block in the tile's own time row that the tile reads across
+        the facet's axis, one tile back (for facet_0, and where the tile
+        is first along the axis, the tile's own block).  Taken through
+        the address maps the tables come from, on the block's first
+        element (inner index 0, modulo label 0, field 0), so a tile's
+        offsets less its anchor's depend only on its halo class, and not
+        on how many time tiles the space has."""
+        t = np.asarray(self.tiling.sizes, np.int64)
+        anchors = {}
+        for k, spec in self.specs.items():
+            q = tiles.copy()
+            if k:
+                q[:, k] = np.maximum(q[:, k] - 1, 0)
+            pts = q * t
+            slab = q[:, k] * t[k] + t[k] - spec.width
+            pts[:, k] = slab + (-slab) % spec.width
+            anchors[k] = self._source_offsets(k, pts)[:len(pts)]
+        return anchors
+
+    def _fetch_rows(self, maps, lo, w) -> dict[int, tuple[np.ndarray, np.ndarray]]:
+        """One tile's table rows per facet array, from its resolved halo:
+        flat source offsets (absolute) and flat halo-buffer destinations;
+        live-in points go into facet_0's row, beside its real points."""
+        strides = row_major_strides(self.halo_shape)
+        src: dict[int, list[np.ndarray]] = {}
+        dst: dict[int, list[np.ndarray]] = {}
+        for key, pts in maps.items():
+            k = 0 if key == "virtual" else key
+            src.setdefault(k, []).append(self._source_offsets(key, pts))
+            dst.setdefault(k, []).append(self._halo_index(pts, lo, w) @ strides)
+        return {k: (np.concatenate(src[k]), np.concatenate(dst[k])) for k in src}
+
+    def fetch_tables(self) -> "FetchPlan":
+        """Every tile resolved on its own, on the host: a
+        :class:`FetchPlan` in which each tile is its own halo class, with
+        absolute offsets and zero shifts.  The compiled fetch's oracle in
+        the tests; the pipeline builds :meth:`fetch_classes`."""
+        tiles = [tuple(int(a) for a in q) for q in self._tile_array()]
+        maps_of, rows = [], []
         for tile in tiles:
             maps, lo, w = self._halo_maps(tile)
-            maps_of[tile] = maps
-            for k in self.specs:
-                src[k].append([])
-                dst[k].append([])
-            for key, pts in maps.items():
-                k = 0 if key == "virtual" else key
-                src[k][-1].append(self._source_offsets(key, pts))
-                dst[k][-1].append(self._halo_index(pts, lo, w) @ strides)
-        keys = tuple(k for k in self.specs if any(src[k]))
+            maps_of.append(maps)
+            rows.append(self._fetch_rows(maps, lo, w))
+        return self._fetch_plan_of(
+            tiles, maps_of, rows, np.arange(len(tiles)),
+            {k: np.zeros(len(tiles), np.int64) for k in self.specs}, share=False)
+
+    def fetch_classes(self) -> "FetchPlan":
+        """The compiled fetch's :class:`FetchPlan` with numpy tables.
+
+        A tile's halo class is read off its coordinates
+        (:meth:`_halo_classes`).  Only each class's first tile is resolved
+        (:meth:`_halo_maps`, so :meth:`_halo_hosts` and the storage
+        discipline apply), its offsets taken relative to its anchor
+        blocks (:meth:`_fetch_anchors`); every tile's shift is its own
+        anchor.  Classes whose rows in one facet array agree share that
+        row, so the tables hold a few rows whatever the tile count."""
+        tiles = self._tile_array()
+        _, first, halo_class = np.unique(self._halo_classes(tiles), axis=0,
+                                         return_index=True, return_inverse=True)
+        anchors = self._fetch_anchors(tiles)
+        tiles = [tuple(int(a) for a in q) for q in tiles]
+        maps_of, rows = [], []
+        for r in first:
+            maps, lo, w = self._halo_maps(tiles[r])
+            maps_of.append(maps)
+            rows.append({k: (s - anchors[k][r], d)
+                         for k, (s, d) in self._fetch_rows(maps, lo, w).items()})
+        return self._fetch_plan_of(tiles, maps_of, rows, halo_class.reshape(-1),
+                                   anchors)
+
+    def _fetch_plan_of(self, tiles, maps_of, rows, halo_class, shifts, *,
+                       share: bool = True) -> "FetchPlan":
+        """Assemble a :class:`FetchPlan` from each halo class's maps and
+        table rows, each tile's class and each facet array's shifts.  Per
+        facet array the rows are padded to one length; with ``share``,
+        classes whose rows agree keep one copy."""
+        shape = self.halo_shape
+        keys = tuple(k for k in self.specs if any(k in r for r in rows))
+        empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
+        src, dst, cls = [], [], []
+        for k in keys:
+            seen: dict = {}
+            distinct, index = [], []
+            for c, r in enumerate(rows):
+                s, d = r.get(k, empty)
+                key = (s.tobytes(), d.tobytes()) if share else c
+                if key not in seen:
+                    seen[key] = len(distinct)
+                    distinct.append((s, d))
+                index.append(seen[key])
+            src.append(_pad_rows([s for s, _ in distinct], 0))
+            dst.append(_pad_rows([d for _, d in distinct], math.prod(shape)))
+            cls.append(np.asarray(index, np.int64)[halo_class])
         return FetchPlan(
-            maps=maps_of, rows={tile: r for r, tile in enumerate(tiles)},
-            keys=keys, shape=shape,
-            src=tuple(_pad_rows(src[k], 0) for k in keys),
-            dst=tuple(_pad_rows(dst[k], math.prod(shape)) for k in keys),
-        )
+            maps=tuple(maps_of),
+            rows={tile: (r, int(c)) for r, (tile, c) in enumerate(zip(tiles, halo_class))},
+            keys=keys, src=tuple(src), dst=tuple(dst),
+            cls=np.stack(cls, axis=1),
+            shift=np.stack([shifts[k] for k in keys], axis=1),
+            shape=shape)
 
     def _build_fetch_plan(self) -> "FetchPlan":
-        """Build this pipeline's fetch plan and upload its tables and row
-        numbers, each once, through :func:`device_index`."""
-        plan = self.fetch_tables()
-        rows = list(device_index(np.arange(len(plan.rows))))
-        self._fetch_plan = dataclasses.replace(
-            plan,
-            rows={tile: rows[r] for tile, r in plan.rows.items()},
-            src=tuple(device_index(s) for s in plan.src),
-            dst=tuple(device_index(d) for d in plan.dst),
-        )
+        """Build this pipeline's fetch plan (:meth:`fetch_classes`, in
+        the ``fetch_plan`` phase), check that every offset a tile gathers
+        at fits the index dtype, and upload its tables and row numbers,
+        each once, through :func:`device_index`."""
+        with obs.phase(self.recorder, "fetch_plan", "fetch"):
+            plan = self.fetch_classes()
+            _check_index(plan.reach())
+            rows = list(device_index(np.arange(len(plan.rows))))
+            self._fetch_plan = dataclasses.replace(
+                plan,
+                rows={tile: (rows[r], c) for tile, (r, c) in plan.rows.items()},
+                src=tuple(device_index(s) for s in plan.src),
+                dst=tuple(device_index(d) for d in plan.dst),
+                cls=device_index(plan.cls),
+                shift=device_index(plan.shift),
+            )
         return self._fetch_plan
+
+    def _sweep_fetch_plan(self) -> None:
+        """Open a sweep over facets on one device: the compiled fetch's
+        plan, built on the first sweep, is counted each sweep (its halo
+        classes, and the bytes its tables hold on the device)."""
+        plan = self._fetch_plan or self._build_fetch_plan()
+        rec = self.recorder
+        if rec is not None:
+            rec.counters.add("fetch_classes", len(plan.maps))
+            rec.counters.add("fetch_table_bytes", plan.nbytes)
 
     # -- execute ---------------------------------------------------------------
 
@@ -675,6 +822,7 @@ class CFAPipeline:
         ``backend="sweep"`` executor's entry point)."""
         rec = self.recorder
         facets = self._loaded_facets(inputs, dtype)
+        self._sweep_fetch_plan()
         if rec is not None:
             rec.counters.add("waves", len(self.wavefronts()))
         for tile in itertools.product(*(range(n) for n in self.num_tiles)):
@@ -719,6 +867,7 @@ class CFAPipeline:
         ``backend="wavefront"``/``"pallas"`` executors' entry point."""
         rec = self.recorder
         facets = self._loaded_facets(inputs, dtype)
+        self._sweep_fetch_plan()
         interior = self._interior_slices(self.widths)
         waves = self.wavefronts()
         if rec is not None:
@@ -780,6 +929,7 @@ class CFAPipeline:
         """
         rec = self.recorder
         facets = self._loaded_facets(inputs, dtype)
+        self._sweep_fetch_plan()
         interior = self._interior_slices(self.widths)
         if use_kernel:
             from repro.kernels.stencil import execute_tiles

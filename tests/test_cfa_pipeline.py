@@ -141,8 +141,10 @@ def test_copy_in_gathers_through_the_offset_guard(monkeypatch):
     pipe.copy_in(facets, (0, 0, 0))  # live-in row only: builds the plan
     plan = pipe._fetch_plan
     assert plan.keys == (0, 1, 2)
-    # the row numbers, then the source and the destination tables
-    assert seen == [(8,)] + [s.shape for s in plan.src] * 2
+    # the row numbers, the source and the destination tables, then each
+    # tile's class and shift in every facet array
+    assert seen == ([(8,)] + [s.shape for s in plan.src] * 2
+                    + [(8, 3), (8, 3)])
     with jax.transfer_guard_host_to_device("disallow"):
         pipe.copy_in(facets, (1, 1, 1))  # one gather per facet array
-    assert len(seen) == 7
+    assert len(seen) == 9

@@ -106,12 +106,14 @@ def test_sharded_kernel_leg_compiles_over_four_chips(topo, as_on_chip):
     ("jacobi2d5p", (200, 250, 250), (20, 50, 125)),
     ("heat3d", (48, 40, 40, 40), (4, 20, 20, 20)),
     ("fdtd2d", (100, 200, 240), FDTD2D_TILE),
+    # fdtd-2d LARGE (fdtd2d-large): 2,000 tiles, tables of a few rows
+    ("fdtd2d", (200, 1000, 1200), FDTD2D_TILE),
 ])
 def test_compiled_fetch_compiles_for_the_chip(program, space, tile, one_chip,
                                               as_on_chip):
     """The fetch program at the cells' facet, table and halo shapes."""
     pipe = CFAPipeline(get_program(program), IterSpace(space), Tiling(tile))
-    plan = pipe.fetch_tables()
+    plan = pipe.fetch_classes()
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -120,6 +122,7 @@ def test_compiled_fetch_compiles_for_the_chip(program, space, tile, one_chip,
         tuple(arg(pipe.facet_shape(k), jnp.float32) for k in plan.keys),
         tuple(arg(s.shape, jnp.int32) for s in plan.src),
         tuple(arg(d.shape, jnp.int32) for d in plan.dst),
+        arg(plan.cls.shape, jnp.int32), arg(plan.shift.shape, jnp.int32),
         arg((), jnp.int32), shape=plan.shape).compile()
     text = compiled.as_text()
     assert "gather" in text and "scatter" in text
